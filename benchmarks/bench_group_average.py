@@ -54,6 +54,7 @@ from repro import compat
 from repro.core import bucketing, grouping
 from repro.core import group_allreduce as ga
 from repro.launch.hlo_analysis import count_ppermutes
+from repro.launch.mesh import make_mesh
 
 OUT_JSON = os.path.join(_ROOT, "BENCH_group_average.json")
 
@@ -239,7 +240,7 @@ def modeled_streamed_fsdp(*, P_cluster: int = 64, n_pods: int = 4,
     from repro.configs import SHAPES, get_config
     from repro.core import plan as plan_mod
     from repro.launch.costmodel import averaging_comm_cost, train_cost
-    from repro.launch.mesh import PEAK_FLOPS
+    from repro.launch.mesh import V5E, chip_peaks
     from repro.models.registry import build_model
 
     cfg = get_config("transformer-wmt")
@@ -254,7 +255,7 @@ def modeled_streamed_fsdp(*, P_cluster: int = 64, n_pods: int = 4,
     # the analytic cost model (flops_per_device = 4x fwd incl. remat)
     n_spans = cfg.n_layers + cfg.encoder_layers
     cm = train_cost(cfg, SHAPES["train_4k"], n_dp=P_cluster, n_model=1)
-    span_fwd_s = cm.flops_per_device / 4.0 / n_spans / PEAK_FLOPS
+    span_fwd_s = cm.flops_per_device / 4.0 / n_spans / chip_peaks(V5E).flops
     rep = averaging_comm_cost(cfg, P=P_cluster,
                               S=grouping.default_group_size(P_cluster),
                               tau=tau, n_leaves=n_leaves,
@@ -315,7 +316,7 @@ def modeled_degraded_mode(*, P_cluster: int = 64, steps: int = 600,
 def live_mesh_bench(args) -> dict:
     """Wall-clock + launch-count measurement on the 8-device CPU mesh."""
     n_dp, S = 8, args.S
-    mesh = jax.make_mesh((n_dp,), ("data",))
+    mesh = make_mesh((n_dp,), ("data",))
     names, sizes = ga.dp_axis_layout(("data",), {"data": n_dp}, ("data",))
     rng = np.random.default_rng(0)
     tree = transformer_like_tree(rng, n_dp, args.layers, args.d)

@@ -49,6 +49,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import compat
 from repro.core.plan import DEFAULT_LINK_CONSTANTS_PATH
+from repro.launch.mesh import make_mesh
 
 # One canonical tracked location (repo root) shared with
 # Topology.with_measured's default — there is no second copy to drift.
@@ -145,7 +146,7 @@ def main():
     dims = tuple(int(x) for x in args.mesh_shape.split(","))
     axes = ("pod", "data", "model")[:len(dims)] if len(dims) != 2 \
         else ("pod", "data")
-    mesh = jax.make_mesh(dims, axes)
+    mesh = make_mesh(dims, axes)
     big_elems = int(args.big_mb * 2**20 / 4)
 
     report = {

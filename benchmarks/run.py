@@ -8,23 +8,15 @@ Prints ``name,us_per_call,derived`` CSV rows:
   fig10_throughput_rl        same for the RL workload, P=1024 (paper Fig. 10)
   fig5_convergence_*         final-loss per SGD variant + ablations 1-4
                              (paper Fig. 5 / §V-B experiments)
-  micro_group_allreduce      measured wall-time of the 8-device butterfly
-                             group-average vs global psum (25.6M params,
-                             ResNet-50-sized payload)      (paper §III)
   table1_collective_bytes    per-device bytes/step per algorithm for the
                              paper's three models           (paper Table I/§VI)
 """
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def row(name: str, us: float, derived):
@@ -65,52 +57,6 @@ def bench_convergence():
         f"{sum(checks.values())}/{len(checks)}")
 
 
-def bench_group_allreduce_micro():
-    """Measured butterfly vs global allreduce on 8 forced-host devices."""
-    script = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys, time
-sys.path.insert(0, %r)
-import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
-from repro.core.wagma import WagmaAverager, WagmaConfig
-from repro.core.group_allreduce import dp_axis_layout
-
-mesh = jax.make_mesh((8,), ("data",))
-names, sizes = dp_axis_layout(("data",), {"data": 8}, ("data",))
-av = WagmaAverager(names, sizes, WagmaConfig(group_size=2))
-N = 25_559_081 // 8  # ResNet-50 params, model-sharded 8-way
-x = {"w": jnp.zeros((8, N), jnp.float32)}
-group = jax.jit(compat.shard_map(lambda t: av.comm(t, 0), mesh=mesh,
-                in_specs=P("data"), out_specs=P("data"), axis_names={"data"}))
-glob = jax.jit(compat.shard_map(av.sync, mesh=mesh,
-               in_specs=P("data"), out_specs=P("data"), axis_names={"data"}))
-for f in (group, glob):
-    f(x)["w"].block_until_ready()
-def t(f, n=10):
-    t0 = time.time()
-    for _ in range(n):
-        out = f(x)
-    out["w"].block_until_ready()
-    return (time.time() - t0) / n * 1e6
-print(f"RESULT,{t(group):.1f},{t(glob):.1f}")
-"""
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, "-c", script % (ROOT + "/src",)],
-                         capture_output=True, text=True, env=env, timeout=300)
-    for line in out.stdout.splitlines():
-        if line.startswith("RESULT"):
-            _, g, a = line.split(",")
-            row("micro_group_allreduce_S2", float(g),
-                f"global_psum_us={a};saving={float(a)/float(g):.2f}x")
-            return
-    row("micro_group_allreduce_S2", -1.0,
-        f"subprocess_failed:{out.stderr[-200:]}")
-
-
 def bench_collective_model():
     from repro.core.group_allreduce import collective_bytes_per_device
     models = {"resnet50": 25.56e6 * 4, "transformer": 61.36e6 * 4,
@@ -129,7 +75,6 @@ def bench_collective_model():
 def main() -> None:
     print("name,us_per_call,derived")
     bench_collective_model()
-    bench_group_allreduce_micro()
     bench_throughput()
     bench_convergence()
 

@@ -9,8 +9,8 @@ Model (DESIGN.md §14):
 
 * Per-phase latencies come from the analytic cost model
   (``prefill_cost`` / ``decode_cost``, launch/costmodel.py) pushed
-  through the chip roofline (``PEAK_FLOPS`` / ``HBM_BW``,
-  launch/mesh.py).
+  through the chip roofline (the v5e entry of
+  ``launch/mesh.CHIP_PEAKS``).
 * KV transfer (disaggregated only) is costed by
   ``plan.link_transfer_seconds`` on the DCN link class at the link's
   modeled-optimal message budget — the same arithmetic the
@@ -60,15 +60,16 @@ from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.core import plan as plan_mod
 from repro.launch import costmodel
-from repro.launch.mesh import PEAK_FLOPS, HBM_BW, HBM_PER_CHIP
+from repro.launch.mesh import V5E, chip_peaks
 from repro.serve.kv_transfer import kv_payload_bytes
 
 OUT_JSON = os.path.join(_ROOT, "BENCH_serving.json")
+PEAKS = chip_peaks(V5E)
 
 
 def _roofline(report) -> float:
-    return max(report.flops_per_device / PEAK_FLOPS,
-               report.hbm_bytes_per_device / HBM_BW)
+    return max(report.flops_per_device / PEAKS.flops,
+               report.hbm_bytes_per_device / PEAKS.hbm_bw)
 
 
 class Latency:
@@ -292,7 +293,7 @@ def main():
     total, _ = costmodel.param_count(cfg)
     weight_bytes = total * 2 / n_model
     kv_tok = kv_payload_bytes(cfg, 1) / n_model
-    kv_budget = 0.9 * HBM_PER_CHIP - weight_bytes
+    kv_budget = 0.9 * PEAKS.hbm_bytes - weight_bytes
     cap_tokens = int(kv_budget / kv_tok)
     max_batch = min(args.max_batch,
                     max(1, cap_tokens // (args.max_prompt + args.max_new)))

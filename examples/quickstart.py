@@ -8,8 +8,7 @@ This is the intended surface of the averaging subsystem (DESIGN.md §9): map
 the dp mesh axes onto link classes with a frozen ``Topology``, and let the
 averager compile the collective once into an ``AveragingPlan`` — per-stage
 ICI/DCN classification, one bucket budget per link class, wavefront
-schedule.  The old ``group_average(offset=..., fused=..., bucket_bytes=...)``
-kwarg pile is a deprecated shim over exactly this.
+schedule.
 
     PYTHONPATH=src python examples/quickstart.py
 """
@@ -19,20 +18,15 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
 
-from repro import compat
 from repro.configs import get_config
 from repro.core.group_allreduce import dp_axis_layout
 from repro.core.plan import Topology
+from repro.launch.mesh import make_mesh
 from repro.launch.train import Trainer
 
 
 def main():
-    # dp x tp needs lax.scan over auto-sharded xs inside a partially-manual
-    # shard_map, which crashes the XLA bundled with JAX 0.4.x — fall back to
-    # pure data parallelism there (see compat.PARTIAL_AUTO_SCAN_OK).
-    n_model = 2 if compat.PARTIAL_AUTO_SCAN_OK else 1
-    n_data = 8 // (2 * n_model)
-    mesh = jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_config("tinyllama-1.1b", smoke=True)
 
     # The topology is the compilation input: the 'data' axis rides intra-pod

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.registry import build_model
 from repro.serve import build_serve_step
 from repro import compat
@@ -27,7 +28,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     args = ap.parse_args()
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_config(args.arch, smoke=True)
     model = build_model(cfg)
 

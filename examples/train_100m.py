@@ -17,6 +17,7 @@ import argparse
 import jax
 
 from repro.configs.base import ModelConfig
+from repro.launch.mesh import make_mesh
 from repro.launch.train import Trainer
 from repro.checkpoint import save_checkpoint
 
@@ -47,15 +48,10 @@ def main():
     args = ap.parse_args()
 
     if args.sharding == "fsdp":
-        # fsdp needs a pod axis to average over once data carries shards;
-        # the dp x tp combination needs the modern toolchain (see
-        # compat.PARTIAL_AUTO_SCAN_OK) so JAX 0.4.x drops the model axis
-        from repro import compat
-        n_model = 2 if compat.PARTIAL_AUTO_SCAN_OK else 1
-        mesh = jax.make_mesh((2, 8 // (2 * n_model), n_model),
-                             ("pod", "data", "model"))
+        # fsdp needs a pod axis to average over once data carries shards
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     else:
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
     cfg = config_100m()
     import numpy as np
     n_params = None

@@ -25,16 +25,6 @@ if git ls-files | grep -qE '(^|/)__pycache__/|\.py[co]$'; then
   exit 1
 fi
 
-# Dev-only deps (hypothesis): install on demand so the 7 property tests run
-# in tier-1 instead of skipping.  Best-effort — offline/air-gapped runners
-# fall back to the hypothesis_compat skip shim and the suite stays green.
-if ! python -c "import hypothesis" >/dev/null 2>&1; then
-  if ! python -m pip install --quiet -r requirements-dev.txt >/dev/null 2>&1; then
-    echo "ci.sh: requirements-dev.txt install failed (offline?);" \
-         "property tests will skip" >&2
-  fi
-fi
-
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python -m pytest -x -q "$@"
 
 # Modeled-perf gate: overlapped < serial for transformer_wmt AND the

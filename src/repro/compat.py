@@ -1,118 +1,47 @@
-"""JAX version-compatibility shims.
+"""The mesh/collective API surface the repo uses, in one place.
 
-The codebase targets the modern mesh/collective API surface —
-``jax.shard_map(..., axis_names=..., check_vma=...)``,
-``jax.set_mesh(mesh)`` and ``jax.sharding.get_abstract_mesh()`` — but must
-also run on JAX 0.4.x, where the same functionality lives under
-``jax.experimental.shard_map`` (``auto=`` / ``check_rep=`` spelling), the
-thread-local mesh is set by entering the ``Mesh`` context manager, and the
-current mesh is read from ``jax._src.mesh.thread_resources``.
-
-Every call site in the repo goes through this module instead of touching the
-moving APIs directly, so a JAX upgrade (or downgrade) is a one-file audit.
+Every call site goes through this module instead of touching the moving
+JAX APIs directly, so a JAX upgrade is a one-file audit.  Written for
+JAX 0.9: ``jax.shard_map(..., axis_names=..., check_vma=...)``,
+``jax.set_mesh(mesh)`` and ``jax.sharding.get_abstract_mesh()``.
 """
 
 from __future__ import annotations
 
-import contextlib
 from typing import Optional, Set
 
 import jax
 
-# Feature probes are done once at import; all of these are plain attribute
-# existence checks (jax's deprecation module raises AttributeError for
-# removed/not-yet-added names, so hasattr is reliable in both directions).
-_HAS_TOPLEVEL_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_SET_MESH = hasattr(jax, "set_mesh")
-_HAS_GET_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-
 
 def get_abstract_mesh():
-    """The mesh currently in scope, as an object exposing ``.empty``,
-    ``.axis_names`` and ``.shape`` (a name->size mapping).
-
-    New JAX: ``jax.sharding.get_abstract_mesh()``.  JAX 0.4.x: the
-    thread-local *physical* mesh installed by entering a ``Mesh`` context
-    (``with mesh:``), which satisfies the same read-only interface.
-    """
-    if _HAS_GET_ABSTRACT_MESH:
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as _mesh_src
-    return _mesh_src.thread_resources.env.physical_mesh
+    """The mesh currently in scope (``.empty``, ``.axis_names``, ``.shape``,
+    ``.axis_types``)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def set_mesh(mesh):
-    """Context manager scoping ``mesh`` as the ambient mesh.
-
-    New JAX: ``jax.set_mesh(mesh)``.  JAX 0.4.x: ``Mesh`` is itself a
-    context manager that installs the thread-local resource env consumed by
-    ``with_sharding_constraint`` and :func:`get_abstract_mesh` above.
-    """
-    if _HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    if mesh is None:
-        return contextlib.nullcontext()
-    return mesh
+    """Context manager scoping ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on any JAX.
-
-    JAX 0.4.x returns a one-element list of per-computation dicts; newer JAX
-    returns the dict directly.  Returns ``{}`` when the backend reports
-    nothing.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca or {}
-
-
-# JAX 0.4.x ships an XLA whose SPMD partitioner hard-crashes
-# (``Check failed: sharding.IsManualSubgroup()``) when a ``lax.scan`` iterates
-# over xs sharded on an *auto* (GSPMD) mesh axis inside a partially-manual
-# shard_map — exactly the layer-stack scan of a tensor-parallel model inside
-# the dp-manual train step.  Fixed upstream; callers (tests, launchers) gate
-# dp x tp runs on this flag.
-PARTIAL_AUTO_SCAN_OK = _HAS_TOPLEVEL_SHARD_MAP
+    """``compiled.cost_analysis()`` as a dict; ``{}`` when the backend
+    reports nothing."""
+    return compiled.cost_analysis() or {}
 
 
 def mesh_axis_types(mesh) -> dict:
-    """``{axis_name: axis_type}`` for meshes that carry axis types.
-
-    Returns ``{}`` on JAX versions (or meshes) without type annotations —
-    callers treat unknown as "no axis is known to be Auto", which degrades
-    to the conservative single-shard_map path.
-    """
-    types = getattr(mesh, "axis_types", None)
-    if types is None:
-        return {}
-    try:
-        return dict(zip(mesh.axis_names, types))
-    except TypeError:
-        return {}
+    """``{axis_name: axis_type}`` of ``mesh``."""
+    return dict(zip(mesh.axis_names, mesh.axis_types))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs,
               axis_names: Optional[Set[str]] = None, check_vma: bool = False):
-    """``jax.shard_map`` with the modern keyword spelling on any JAX.
+    """``jax.shard_map`` with the replication checker off by default.
 
     ``axis_names`` is the set of mesh axes the body is *manual* over (the
-    rest stay auto/GSPMD); ``check_vma`` is the replication-checker toggle
-    (named ``check_rep`` on 0.4.x).  ``None`` axis_names means manual over
-    every mesh axis, matching upstream semantics.
+    rest stay auto/GSPMD); ``None`` means manual over every mesh axis.
     """
-    if _HAS_TOPLEVEL_SHARD_MAP:
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma,
-                             **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if axis_names is None:
-        auto = frozenset()
-    else:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
+    kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)

@@ -568,10 +568,34 @@ def modeled_streamed_fsdp_step_seconds(
 
 def _stage_combine(acc, recv, scale: float, use_pallas: bool):
     """(acc + recv) * scale — fused Pallas kernel or plain jnp."""
-    if use_pallas:
-        from repro.kernels import ops
-        return ops.group_average_combine(acc, recv, scale)
-    return (acc + recv) * jnp.asarray(scale, acc.dtype)
+    return _combine_many([acc], [recv], scale, use_pallas)[0]
+
+
+def _manual_over_auto_axes(kernel, accs, recvs):
+    """Call ``kernel(accs, recvs) -> outs`` manual over every mesh axis.
+
+    A Mosaic kernel cannot be partitioned by GSPMD, so it must sit where
+    every mesh axis is Manual.  Inside the train step's shard_map (manual
+    over the dp axes) the ``model`` axis is still Auto — even at size 1 —
+    so the call is wrapped in a nested shard_map over the remaining Auto
+    axes.  The combine is elementwise: a flat buffer that divides evenly
+    is split over those axes (each tensor-parallel rank combines its
+    slice), anything else is combined whole on every rank.
+    """
+    from jax.sharding import AxisType, PartitionSpec as P
+    from repro import compat
+    mesh = compat.get_abstract_mesh()
+    auto = () if mesh.empty else tuple(
+        a for a, t in zip(mesh.axis_names, mesh.axis_types)
+        if t != AxisType.Manual)
+    if not auto:
+        return kernel(accs, recvs)
+    n = math.prod(mesh.shape[a] for a in auto)
+    specs = [P(auto) if a.ndim == 1 and a.shape[0] % n == 0 else P()
+             for a in accs]
+    return compat.shard_map(kernel, mesh=mesh, in_specs=(specs, specs),
+                            out_specs=specs,
+                            axis_names=set(mesh.axis_names))(accs, recvs)
 
 
 def _combine_many(accs, recvs, scale: float, use_pallas: bool):
@@ -579,7 +603,7 @@ def _combine_many(accs, recvs, scale: float, use_pallas: bool):
 
     The Pallas route groups the batch by dtype and feeds each group to ONE
     multi-bucket kernel launch (grid walks buckets x row-tiles); the jnp
-    route does the same per-pair arithmetic as :func:`_stage_combine`.
+    route does the same per-pair arithmetic.
     """
     if not use_pallas:
         return [(a + r) * jnp.asarray(scale, a.dtype)
@@ -590,8 +614,9 @@ def _combine_many(accs, recvs, scale: float, use_pallas: bool):
     for i, a in enumerate(accs):
         by_dtype.setdefault(jnp.dtype(a.dtype), []).append(i)
     for idxs in by_dtype.values():
-        res = ops.group_average_combine_multi([accs[i] for i in idxs],
-                                              [recvs[i] for i in idxs], scale)
+        res = _manual_over_auto_axes(
+            lambda ws, rs: ops.group_average_combine_multi(ws, rs, scale),
+            [accs[i] for i in idxs], [recvs[i] for i in idxs])
         for i, o in zip(idxs, res):
             outs[i] = o
     return outs
@@ -869,19 +894,29 @@ class AveragingPlan:
     def stream_unshard(self, shards, group: int, *, barrier: bool = False):
         """One group's shard slices -> its full sub-tree (all-gather on ICI).
 
-        ``barrier=True`` fences the operands through
-        ``lax.optimization_barrier`` — backward *re*-gathers must not CSE
-        with the forward gathers, or XLA keeps the forward buffers alive
+        ``barrier=True`` marks a backward *re*-gather, which must not CSE
+        with the forward gather, or XLA keeps the forward buffers alive
         and the streamed memory bound silently degrades to gather-all.
+        It gathers the buffers' bits as same-width unsigned integers: a
+        different all-gather operand, bit-identical after the bitcast
+        back.  (``lax.optimization_barrier`` does not fence it: XLA
+        expands barriers before its last CSE pass.)
         """
         self._require_streamed()
         ax = self.sharding.shard_axis
-        bufs = tuple(shards[i] for i in self.stream_bucket_indices(group))
-        if barrier:
-            bufs = streaming._barrier(bufs)
-        gathered = tuple(
-            jax.lax.all_gather(b, ax, tiled=True) if b.size else
-            jnp.zeros((0,), b.dtype) for b in bufs)
+
+        def gather(b):
+            if not b.size:
+                return jnp.zeros((0,), b.dtype)
+            if not barrier:
+                return jax.lax.all_gather(b, ax, tiled=True)
+            bits = jnp.dtype(f"uint{8 * b.dtype.itemsize}")
+            g = jax.lax.all_gather(jax.lax.bitcast_convert_type(b, bits),
+                                   ax, tiled=True)
+            return jax.lax.bitcast_convert_type(g, b.dtype)
+
+        gathered = tuple(gather(shards[i])
+                         for i in self.stream_bucket_indices(group))
         return bucketing.unpack(gathered, self.stream_sublayout(group))
 
     def stream_grad_shards(self, grad_subtree, group: int) -> tuple:

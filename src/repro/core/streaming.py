@@ -30,8 +30,9 @@ reduce-scatter must accumulate in fp32 regardless of the storage dtype
 before the ``psum_scatter``, exactly like the gather-all path's
 ``grad_shards``), and (b) backward re-gathers must not be CSE'd with the
 forward gathers (XLA would otherwise keep the forward buffer alive and
-silently restore gather-all memory) — re-gather operands pass through
-``lax.optimization_barrier``.  Because the per-span primal/VJP ops are the
+silently restore gather-all memory) — a re-gather moves the buffers' bits
+as unsigned integers, an all-gather of a different operand that CSE cannot
+merge with the forward one.  Because the per-span primal/VJP ops are the
 same ops ``jax.value_and_grad(model.loss)`` runs on the gathered tree, the
 streamed step is **bit-identical** to the gather-all step (pinned by
 tests/test_streaming.py on every phase offset).
@@ -214,12 +215,6 @@ def expected_stream_gathers(plan) -> int:
         1 for s, g in zip(lay.bucket_sizes, lay.bucket_groups)
         if s and 0 < g <= plan.n_stream_spans)
     return n_real + n_span_real
-
-
-def _barrier(x):
-    """CSE fence for backward re-gathers (identity on old jax)."""
-    opt = getattr(jax.lax, "optimization_barrier", None)
-    return opt(x) if opt is not None else x
 
 
 # ---------------------------------------------------------------------------

@@ -53,6 +53,7 @@ def _force_host_device_count(n: int = 512) -> None:
 
 from repro.configs import SHAPES, arch_names, get_config
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch import specs as specs_lib
 from repro.launch.hlo_analysis import collective_summary
 from repro.launch.costmodel import cost_for, param_count
@@ -406,6 +407,7 @@ def lower_pair(arch: str, shape_name: str, mesh, *, averager: str = "wagma",
 
 def main():
     _force_host_device_count()          # before any jax device/compile use
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(SHAPES) + [None])
@@ -455,7 +457,7 @@ def main():
             dims = tuple(int(x) for x in args.mesh_shape.split(","))
             axes = ("pod", "data", "model") if len(dims) == 3 \
                 else ("data", "model")
-            mesh = jax.make_mesh(dims, axes)
+            mesh = mesh_lib.make_mesh(dims, axes)
             mesh_tag = "x".join(str(d) for d in dims)
         else:
             mesh = mesh_lib.make_production_mesh(multi_pod=mp)

@@ -17,7 +17,9 @@ import glob
 import json
 import os
 
-from repro.launch.mesh import PEAK_FLOPS, HBM_BW, ICI_BW, HBM_PER_CHIP
+from repro.launch.mesh import V5E, chip_peaks
+
+PEAKS = chip_peaks(V5E)
 
 ADVICE = {
     ("compute",): "raise arithmetic intensity: larger per-device batch or "
@@ -33,11 +35,11 @@ ADVICE = {
 def analyse(rec: dict) -> dict:
     a = rec["analytic"]
     colls = rec["collectives"]
-    compute = a["flops_per_device"] / PEAK_FLOPS
-    memory = a["hbm_bytes_per_device"] / HBM_BW
+    compute = a["flops_per_device"] / PEAKS.flops
+    memory = a["hbm_bytes_per_device"] / PEAKS.hbm_bw
     wire = colls.get("total_wire_bytes_tpu_adjusted",
                      colls["total_wire_bytes"])
-    collective = wire / ICI_BW
+    collective = wire / PEAKS.ici_bw
     terms = {"compute": compute, "memory": memory, "collective": collective}
     dom = max(terms, key=terms.get)
     bound = max(terms.values())
@@ -53,7 +55,7 @@ def analyse(rec: dict) -> dict:
         "roofline_fraction": compute / bound if bound else 0.0,
         "useful_flop_ratio": useful,
         "hbm_per_device_GiB": mem_dev / 2**30,
-        "fits_hbm": mem_dev <= HBM_PER_CHIP,
+        "fits_hbm": mem_dev <= PEAKS.hbm_bytes,
         "advice": ADVICE[(dom,)],
     }
 

@@ -4,10 +4,11 @@ Builds the mesh, model, optimiser, and averager; maintains the cache of
 compiled step variants (one per butterfly phase offset + the tau-sync step);
 streams synthetic data; logs metrics; checkpoints.
 
-Usage (CPU demo on forced host devices is in examples/; on a real pod run):
+Usage (CPU demo on forced host devices is in examples/; with no mesh flags
+the run is data parallel over every device present):
 
     python -m repro.launch.train --arch tinyllama-1.1b --averager wagma \
-        --steps 500 --data-axis 16 --model-axis 16 [--multi-pod]
+        --steps 500 [--data-axis 16 --model-axis 16 [--pod-axis 2]]
 """
 
 from __future__ import annotations
@@ -31,6 +32,8 @@ from repro.optim import sgd, adamw, cosine_warmup
 from repro.train import build_train_step, init_replica_state, dp_axes_of
 from repro.checkpoint import save_replica_state
 from repro import compat
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 
 
 def resolve_sharding(sharding, dp_names, streamed: bool = False
@@ -179,6 +182,11 @@ class Trainer:
             self.last_metrics.get("skipped_nonfinite", 0.0) * self.n_dp
         return float(metrics["loss"])
 
+    def step_hlo(self, t: int) -> str:
+        """Compiled HLO text of the step variant global step ``t`` runs."""
+        return self._step_fn(t).lower(self.state,
+                                      self._put_batch(t)).compile().as_text()
+
     def run(self, steps: int, log_every: int = 10, ckpt_dir=None,
             ckpt_every=0):
         history = []
@@ -220,13 +228,14 @@ def main():
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--seq-len", type=int, default=512)
     ap.add_argument("--global-batch", type=int, default=None)
-    ap.add_argument("--data-axis", type=int, default=None)
+    ap.add_argument("--data-axis", type=int, default=None,
+                    help="default: every device present over the "
+                         "pod/model axes")
     ap.add_argument("--model-axis", type=int, default=None)
     ap.add_argument("--pod-axis", type=int, default=None,
-                    help="with --data-axis: build a (pod, data, model) "
-                         "mesh — required for --sharding fsdp (the pod "
-                         "axis carries the pod-to-pod averaging)")
-    ap.add_argument("--multi-pod", action="store_true")
+                    help="build a (pod, data, model) mesh — required for "
+                         "--sharding fsdp (the pod axis carries the "
+                         "pod-to-pod averaging)")
     ap.add_argument("--pod-dcn", action="store_true",
                     help="hierarchical topology: the pod axis rides DCN "
                          "constants/budget, data rides ICI (DESIGN.md §9)")
@@ -246,16 +255,16 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
 
-    if args.data_axis and args.pod_axis:
-        mesh = jax.make_mesh(
-            (args.pod_axis, args.data_axis, args.model_axis or 1),
-            ("pod", "data", "model"))
-    elif args.data_axis:
-        mesh = jax.make_mesh((args.data_axis, args.model_axis or 1),
-                             ("data", "model"))
+    enable_compile_cache()
+    model_axis = args.model_axis or 1
+    pod_axis = args.pod_axis or 1
+    # no --data-axis: data parallel over every device present
+    data_axis = args.data_axis or jax.device_count() // (pod_axis * model_axis)
+    if args.pod_axis:
+        mesh = make_mesh((pod_axis, data_axis, model_axis),
+                         ("pod", "data", "model"))
     else:
-        from repro.launch.mesh import make_production_mesh
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+        mesh = make_mesh((data_axis, model_axis), ("data", "model"))
 
     cfg = get_config(args.arch, smoke=args.smoke)
     topology = None

@@ -122,9 +122,10 @@ def _model_shapes(model):
 
     Cached per model object: every step-variant build and spec derivation
     re-asks for the same shapes, and eval_shape re-traces ``model.init``
-    each time otherwise.
+    each time otherwise.  The key is made inside the trace: nothing may
+    run eagerly under a mesh of described (unattached) TPU devices.
     """
-    return jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    return jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
 
 
 @functools.lru_cache(maxsize=32)

@@ -1,7 +1,7 @@
 """Shared pytest setup: make tests/ sibling modules importable.
 
 pytest's rootdir insertion usually handles this, but the explicit insert
-keeps ``import hypothesis_compat`` working under any invocation style
+keeps ``from subproc import run_sub`` working under any invocation style
 (``pytest tests/...``, ``python -m pytest`` from a parent dir, IDE runners).
 """
 

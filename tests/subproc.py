@@ -20,9 +20,10 @@ def run_sub(body: str, devices: int = 8, timeout: int = 420,
             preamble: str = "") -> str:
     """Run dedented ``body`` on ``devices`` forced host devices.
 
-    The script sees jax/jnp/np, PartitionSpec P, NamedSharding, and
-    ``repro.compat`` pre-imported; ``preamble`` (also dedented) can add
-    test-module-specific helpers before the body runs.
+    The script sees jax/jnp/np, PartitionSpec P, NamedSharding,
+    ``repro.compat`` and the mesh constructor ``make_mesh`` pre-imported;
+    ``preamble`` (also dedented) can add test-module-specific helpers
+    before the body runs.
     """
     script = textwrap.dedent(f"""
         import os
@@ -33,6 +34,7 @@ def run_sub(body: str, devices: int = 8, timeout: int = 420,
         import numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro import compat
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(preamble) + textwrap.dedent(body)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)

@@ -238,3 +238,16 @@ def test_cluster_sim_bucketing_win():
     # same payload, fewer launches -> strictly cheaper step in the model
     assert comm_time(50e6, 64, 8, "wagma", n_buckets=4) < \
         comm_time(50e6, 64, 8, "wagma", n_buckets=300)
+
+
+def test_chip_peaks_table_keyed_by_device_kind():
+    """The roofline peaks come from one table keyed by ``device_kind``; a
+    chip with no published entry is an error, never a v5e default."""
+    from repro.launch import roofline
+    from repro.launch.mesh import V5E, chip_peaks
+    v5e = chip_peaks("TPU v5 lite")
+    assert V5E == "TPU v5 lite" and roofline.PEAKS == v5e
+    assert (v5e.flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
+                                                      16 * 2**30)
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("cpu")

@@ -2,18 +2,14 @@
 the main pytest process keeps the real single-device CPU view (the dry-run
 flag must never be set globally — see the system design notes)."""
 
-import sys
-
-import pytest
-
-from subproc import SRC, run_sub
+from subproc import run_sub
 
 
 def test_butterfly_group_average_equals_stacked_simulator():
     out = run_sub("""
         from repro.core import group_allreduce as ga
         from repro.core.wagma import WagmaAverager, WagmaConfig
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         names, sizes = ga.dp_axis_layout(("pod", "data"), dict(pod=2, data=4),
                                          ("pod", "data"))
         av = WagmaAverager(names, sizes, WagmaConfig(group_size=4))
@@ -33,17 +29,6 @@ def test_butterfly_group_average_equals_stacked_simulator():
     assert "MATCH" in out
 
 
-def _partial_auto_scan_ok():
-    import sys
-    sys.path.insert(0, SRC)
-    from repro import compat
-    return compat.PARTIAL_AUTO_SCAN_OK
-
-
-@pytest.mark.skipif(not _partial_auto_scan_ok(), reason=(
-    "JAX 0.4.x XLA crashes (IsManualSubgroup check) on lax.scan over "
-    "auto-axis-sharded xs inside a partially-manual shard_map; the dp x tp "
-    "train step needs a newer JAX"))
 def test_wagma_train_step_loss_decreases_and_sync_equalises():
     out = run_sub("""
         from repro.configs import get_config, SHAPES
@@ -54,7 +39,7 @@ def test_wagma_train_step_loss_decreases_and_sync_equalises():
         from repro.core.group_allreduce import dp_axis_layout
         from repro.train import build_train_step, init_replica_state
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("qwen3-0.6b", smoke=True)
         model = build_model(cfg)
         names, sizes = dp_axis_layout(mesh.axis_names, dict(mesh.shape),
@@ -89,7 +74,7 @@ def test_all_baseline_averagers_compile_and_preserve_mean():
     out = run_sub("""
         from repro.core.baselines import make_averager
         from repro.core.group_allreduce import dp_axis_layout
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         names, sizes = dp_axis_layout(("data",), {"data": 8}, ("data",))
         W = np.random.default_rng(1).normal(size=(8, 40)).astype(np.float32)
         tree = {"w": jnp.asarray(W)}
@@ -117,7 +102,7 @@ def test_grad_averager_allreduce_matches_single_worker_equivalent():
         from repro.core.group_allreduce import dp_axis_layout
         from repro.train import build_train_step, init_replica_state
 
-        mesh = jax.make_mesh((4, 1), ("data", "model"))
+        mesh = make_mesh((4, 1), ("data", "model"))
         cfg = get_config("tinyllama-1.1b", smoke=True).variant(dtype="float32")
         model = build_model(cfg)
         names, sizes = dp_axis_layout(mesh.axis_names, dict(mesh.shape), ("data",))

@@ -309,7 +309,7 @@ def test_kill_rejoin_training_survives_and_rejoiner_bit_identical():
 # Property: controller invariants under adversarial interleavings
 # ---------------------------------------------------------------------------
 
-from hypothesis_compat import given, settings, st  # noqa: E402
+from hypothesis import given, settings, strategies as st  # noqa: E402
 
 _OPS = ("leave", "join", "barrier")
 
@@ -372,8 +372,8 @@ def test_membership_invariants_property(ops, pool):
 
 @pytest.mark.parametrize("seed", range(6))
 def test_membership_invariants_seeded_interleavings(seed):
-    """Deterministic stand-in for the property test when hypothesis is
-    unavailable: seeded random 60-op interleavings over a 4..12 pool."""
+    """Fixed-seed complement to the property test: seeded random 60-op
+    interleavings over a 4..12 pool."""
     rng = np.random.default_rng(seed)
     pool = int(rng.integers(4, 13))
     ops = [(_OPS[int(rng.integers(3))], int(rng.integers(14)))

@@ -51,7 +51,7 @@ def test_fused_equals_per_leaf_equals_stacked_every_offset():
     """The acceptance gate: all realisations agree on every phase offset."""
     out = run_sub("""
         P_dp, S = 8, 4
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         names, sizes = ga.dp_axis_layout(("pod", "data"), dict(pod=2, data=4),
                                          ("pod", "data"))
         rng = np.random.default_rng(0)
@@ -96,7 +96,7 @@ def test_ppermute_count_drops_to_buckets_times_stages():
     out = run_sub("""
         from repro.core import plan as plan_mod
         P_dp, S = 8, 4
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         names, sizes = ga.dp_axis_layout(("data",), {"data": 8}, ("data",))
         rng = np.random.default_rng(1)
         tree = {f"l{i}": jnp.asarray(rng.normal(size=(8, 40)), jnp.float32)
@@ -135,7 +135,7 @@ def test_ppermute_count_drops_to_buckets_times_stages():
 
 def test_global_average_fused_matches_per_leaf():
     out = run_sub("""
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(2)
         tree = mixed_tree(rng, 8)
         local = jax.tree.map(lambda a: a[0], tree)
@@ -165,7 +165,7 @@ def test_global_average_fused_matches_per_leaf():
 def test_baseline_averagers_fused_matches_per_leaf(name):
     out = run_sub(f"""
         from repro.core.baselines import make_averager
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         names, sizes = ga.dp_axis_layout(("data",), {{"data": 8}}, ("data",))
         rng = np.random.default_rng(3)
         tree = {{"w": jnp.asarray(rng.normal(size=(8, 40)), jnp.float32),
@@ -192,7 +192,7 @@ def test_wagma_averager_fused_config_round_trip():
     """WagmaConfig(fused=...) end to end through the averager, incl. sync."""
     out = run_sub("""
         from repro.core.wagma import WagmaAverager, WagmaConfig
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         names, sizes = ga.dp_axis_layout(("data",), {"data": 8}, ("data",))
         rng = np.random.default_rng(4)
         tree = mixed_tree(rng, 8)

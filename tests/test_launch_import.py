@@ -65,3 +65,51 @@ assert "XLA_FLAGS" not in os.environ
 import jax
 assert jax.device_count() == 1, jax.device_count()
 """)
+
+
+def _cache_script(env_dir) -> str:
+    return f"""
+import os, sys
+sys.path.insert(0, {SRC!r})
+from repro.launch import compile_cache
+import jax
+got = compile_cache.enable_compile_cache()
+assert jax.config.jax_compilation_cache_dir == got, got
+want = {env_dir!r}
+if want is None:
+    assert got == os.path.join(os.path.dirname({SRC!r}), ".jax_cache"), got
+else:
+    assert got == want, got
+    hits = []
+    jax.monitoring.register_event_listener(
+        lambda e, **_: hits.append(e)
+        if e == "/jax/compilation_cache/cache_hits" else None)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.jit(lambda x: x * 3 + 1)(jax.numpy.arange(5.0)).block_until_ready()
+    print("HITS", len(hits))
+"""
+
+
+def test_compile_cache_fixed_repo_path_without_env():
+    """No ``JAX_COMPILATION_CACHE_DIR``: the cache goes to ``.jax_cache`` at
+    the root of the checkout (the helper compiles nothing here)."""
+    env = dict(os.environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    out = subprocess.run([sys.executable, "-c", _cache_script(None)],
+                         capture_output=True, text=True, env=env, timeout=240)
+    assert out.returncode == 0, out.stderr[-3000:]
+
+
+def test_compile_cache_env_dir_wins_and_hits_on_second_run(tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` stands: entries land there, and a second
+    identical process reads them back."""
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    env.pop("XLA_FLAGS", None)
+    runs = [subprocess.run([sys.executable, "-c", _cache_script(str(tmp_path))],
+                           capture_output=True, text=True, env=env,
+                           timeout=240) for _ in range(2)]
+    for r in runs:
+        assert r.returncode == 0, r.stderr[-3000:]
+    hits = [int(r.stdout.split("HITS")[1]) for r in runs]
+    assert hits[0] == 0 and hits[1] > 0, hits
+    assert any(tmp_path.iterdir())

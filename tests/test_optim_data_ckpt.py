@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import consolidate, load_checkpoint, save_checkpoint
 from repro.configs import SHAPES, get_config

@@ -116,7 +116,7 @@ def test_overlapped_equals_serial_equals_per_leaf_every_offset():
     simulator for every phase offset, bit-for-bit under fp32 accumulation."""
     out = run_sub("""
         P_dp, S = 8, 4
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         names, sizes = ga.dp_axis_layout(("pod", "data"), dict(pod=2, data=4),
                                          ("pod", "data"))
         rng = np.random.default_rng(0)
@@ -170,7 +170,7 @@ def test_overlap_preserves_launch_count_and_matches_hlo():
     out = run_sub("""
         from repro.core import plan as plan_mod
         P_dp, S = 8, 4
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         names, sizes = ga.dp_axis_layout(("data",), {"data": 8}, ("data",))
         rng = np.random.default_rng(1)
         tree = {f"l{i}": jnp.asarray(rng.normal(size=(8, 40)), jnp.float32)
@@ -217,7 +217,7 @@ def test_wagma_averager_overlap_round_trip():
     """WagmaConfig(overlap=...) end to end through the averager + sync."""
     out = run_sub("""
         from repro.core.wagma import WagmaAverager, WagmaConfig
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         names, sizes = ga.dp_axis_layout(("data",), {"data": 8}, ("data",))
         rng = np.random.default_rng(4)
         tree = mixed_tree(rng, 8)
@@ -249,7 +249,7 @@ def test_wagma_averager_overlap_round_trip():
 def test_baseline_averagers_overlap_matches_serial(name):
     out = run_sub(f"""
         from repro.core.baselines import make_averager
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         names, sizes = ga.dp_axis_layout(("data",), {{"data": 8}}, ("data",))
         rng = np.random.default_rng(3)
         tree = {{"w": jnp.asarray(rng.normal(size=(8, 40)), jnp.float32),

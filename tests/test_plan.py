@@ -289,7 +289,7 @@ def test_plan_average_bit_identical_to_legacy_paths_every_offset():
     kwarg shims' realisations, now expressed as plan configs)."""
     out = run_sub("""
         P_dp, S = 8, 4
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         names, sizes = ga.dp_axis_layout(("pod", "data"), dict(pod=2, data=4),
                                          ("pod", "data"))
         rng = np.random.default_rng(0)
@@ -346,7 +346,7 @@ def test_hierarchical_plan_bit_identical_every_offset():
     simulator on every phase offset (fp32 continuity across runs)."""
     out = run_sub("""
         P_dp, S = 8, 4
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         names, sizes = ga.dp_axis_layout(("pod", "data"), dict(pod=2, data=4),
                                          ("pod", "data"))
         rng = np.random.default_rng(7)
@@ -399,7 +399,7 @@ def test_hierarchical_launch_counts_per_class_match_jaxpr_and_hlo():
     per-class split (ICI launches on 'data', DCN launches on 'pod')."""
     out = run_sub("""
         P_dp, S = 8, 4
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         names, sizes = ga.dp_axis_layout(("pod", "data"), dict(pod=2, data=4),
                                          ("pod", "data"))
         rng = np.random.default_rng(1)
@@ -446,7 +446,7 @@ def test_wagma_averager_with_topology_and_dryrun_summary():
         from repro.core.wagma import WagmaAverager, WagmaConfig
         from repro.launch.dryrun import bucket_collective_summary
         P_dp, S = 8, 4
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         names, sizes = ga.dp_axis_layout(("pod", "data"), dict(pod=2, data=4),
                                          ("pod", "data"))
         rng = np.random.default_rng(4)
@@ -501,7 +501,7 @@ def test_baseline_plans_use_class_budgets():
     out = run_sub("""
         from repro.core.baselines import make_averager
         P_dp = 8
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         names, sizes = ga.dp_axis_layout(("pod", "data"), dict(pod=2, data=4),
                                          ("pod", "data"))
         rng = np.random.default_rng(3)

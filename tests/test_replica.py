@@ -319,7 +319,7 @@ def test_fsdp_average_bit_identical_to_replicated_every_offset():
     offset, for flat AND hierarchical topologies and for the overlapped,
     serial, and jnp-combine realisations."""
     out = run_sub("""
-        mesh = jax.make_mesh((4, 2), ("pod", "data"))
+        mesh = make_mesh((4, 2), ("pod", "data"))
         rng = np.random.default_rng(0)
         pods = [pod_tree(rng) for _ in range(4)]
         stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *pods)
@@ -387,7 +387,7 @@ def test_fsdp_shard_round_trip_sync_and_launch_counts():
     the jaxpr ppermute count equals the plan expectation on every offset
     (launch counts unchanged by sharding)."""
     out = run_sub("""
-        mesh = jax.make_mesh((4, 2), ("pod", "data"))
+        mesh = make_mesh((4, 2), ("pod", "data"))
         rng = np.random.default_rng(3)
         pods = [pod_tree(rng) for _ in range(4)]
         stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *pods)
@@ -454,7 +454,7 @@ def test_fsdp_train_step_wagma_and_allreduce():
         from repro.core.group_allreduce import dp_axis_layout
         from repro.train import build_train_step, init_replica_state
 
-        mesh = jax.make_mesh((2, 4, 1), ("pod", "data", "model"))
+        mesh = make_mesh((2, 4, 1), ("pod", "data", "model"))
         cfg = get_config("qwen3-0.6b", smoke=True)
         model = build_model(cfg)
         names, sizes = dp_axis_layout(mesh.axis_names, dict(mesh.shape),

@@ -16,7 +16,7 @@ def test_cache_sharding_heuristics_8dev():
     out = run_sub("""
         from repro.serve.decode import cache_shardings
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
 
         def spec_of(shape, batch):
             leaf = jax.ShapeDtypeStruct(shape, jnp.float32)
@@ -47,7 +47,7 @@ def test_cache_sharding_heuristics_8dev():
             raise AssertionError("indivisible cache leaf did not raise")
 
         # hierarchical dp: (pod, data) both carry the batch dim
-        mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
         leaf = jax.ShapeDtypeStruct((2, 8, 64, 2, 16), jnp.float32)
         spec = cache_shardings(mesh3, {"x": leaf}, 8)["x"].spec
         assert spec == P(None, ("pod", "data"), None, None, "model"), spec
@@ -63,7 +63,7 @@ def test_serve_step_sharded_decode_8dev():
         from repro.serve.decode import (build_serve_step, cache_shardings,
                                         serve_param_shardings)
 
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         cfg = get_config("qwen3-0.6b", smoke=True)
         model = build_model(cfg)
         B, S = 8, 32

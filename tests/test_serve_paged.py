@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings, strategies as st
 from subproc import run_sub
 
 from repro.configs import get_config
@@ -212,7 +212,7 @@ def test_serving_e2e_8dev_bit_exact():
         from repro.serve import kv_cache
         from repro.serve.scheduler import Request, ServeScheduler
 
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         cfg = get_config("qwen3-0.6b", smoke=True)
         model = build_model(cfg)
         bs, max_blocks = 4, 8

@@ -534,7 +534,7 @@ def test_streamed_plan_average_bit_identical_every_offset():
     bit-identical to the replicated plan on the pod axis and the stacked
     simulator, on every phase offset, flat AND hierarchical."""
     out = run_sub("""
-        mesh = jax.make_mesh((4, 2), ("pod", "data"))
+        mesh = make_mesh((4, 2), ("pod", "data"))
         rng = np.random.default_rng(0)
         pods = [layered_tree(rng) for _ in range(4)]
         stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *pods)
@@ -608,7 +608,7 @@ def test_streamed_train_step_bit_exact_vs_gather_all():
         from repro.train import build_train_step, init_replica_state
         from repro.train.train_step import _plan_of
 
-        mesh = jax.make_mesh((4, 2, 1), ("pod", "data", "model"))
+        mesh = make_mesh((4, 2, 1), ("pod", "data", "model"))
         cfg = get_config("qwen3-0.6b", smoke=True).variant(dtype="float32")
         model = build_model(cfg)
         names, sizes = dp_axis_layout(mesh.axis_names, dict(mesh.shape),

@@ -12,6 +12,7 @@ from repro.configs.base import InputShape, ModelConfig
 from repro.core import staleness
 from repro.core.group_allreduce import global_average_stacked
 from repro.data import make_batch_fn
+from repro.launch.mesh import make_mesh
 from repro.models.registry import build_model
 from repro.optim import sgd
 
@@ -81,7 +82,7 @@ def test_trainer_driver_end_to_end():
     """Single-device Trainer path (mesh 1x1): compiled-variant cache,
     metrics, consolidation."""
     from repro.launch.train import Trainer
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_config("qwen3-0.6b", smoke=True)
     tr = Trainer(cfg, mesh, averager="wagma", group_size=1, tau=3,
                  learning_rate=0.3, seq_len=32, global_batch=4)
@@ -94,7 +95,7 @@ def test_trainer_driver_end_to_end():
 
 def test_serving_greedy_decode_deterministic():
     from repro.serve import build_serve_step
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_config("tinyllama-1.1b", smoke=True)
     model = build_model(cfg)
     with compat.set_mesh(mesh):
