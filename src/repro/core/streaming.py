@@ -248,6 +248,13 @@ def streamed_loss_and_grad_shards(plan, layered, shards, batch, *,
         raise ValueError(f"plan has {plan.n_stream_spans} spans, "
                          f"model decomposes into {n}")
 
+    def fwd(fn, *args, **kw):
+        # the model under the ``forward`` scope: the VJPs below then name
+        # their backward ``transpose(jvp(forward))``, as ``jax.grad`` does
+        # on the gather-all path
+        with jax.named_scope("forward"):
+            return fn(*args, **kw)
+
     gathered: Dict[int, object] = {}
     regathered: set = set()
     boundary: Dict[int, object] = {}      # span group -> its input carry
@@ -264,25 +271,26 @@ def streamed_loss_and_grad_shards(plan, layered, shards, batch, *,
         elif ph == COMPUTE:
             if g == STEM_GROUP:
                 stem_tree = gathered[STEM_GROUP]   # live until its own VJP
-                carry, aux = layered.stem(stem_tree, batch)
+                carry, aux = fwd(layered.stem, stem_tree, batch)
             else:
                 boundary[g] = carry
                 # forward primal only — no residuals are kept (the backward
                 # re-gathers and re-runs the span inside its VJP), so the
                 # remat flag is irrelevant here
-                carry = layered.span(g - 1, gathered.pop(g), carry, aux,
-                                     remat=False)
+                carry = fwd(layered.span, g - 1, gathered.pop(g), carry,
+                            aux, remat=False)
         elif ph == GRAD:
             if g == head:
                 loss, vjp_fn, metrics = jax.vjp(
-                    lambda h, s, c: layered.head_loss(h, s, c, aux, batch),
+                    lambda h, s, c: fwd(layered.head_loss, h, s, c, aux,
+                                        batch),
                     gathered.pop(head), stem_tree, carry, has_aux=True)
                 d_head, d_stem_head, d_carry = vjp_fn(
                     jnp.ones((), loss.dtype))
                 pending[head] = d_head
             elif g == STEM_GROUP:
                 _, vjp_fn = jax.vjp(
-                    lambda s: layered.stem(s, batch)[0], stem_tree)
+                    lambda s: fwd(layered.stem, s, batch)[0], stem_tree)
                 (d_stem,) = vjp_fn(d_carry)
                 # tied unembeddings contribute through the head too; for
                 # untied models the head cotangent is zeros and the add is
@@ -291,7 +299,8 @@ def streamed_loss_and_grad_shards(plan, layered, shards, batch, *,
                     jnp.add, d_stem, d_stem_head)
             else:
                 _, vjp_fn = jax.vjp(
-                    lambda p, c: layered.span(g - 1, p, c, aux, remat=remat),
+                    lambda p, c: fwd(layered.span, g - 1, p, c, aux,
+                                     remat=remat),
                     gathered.pop(g), boundary.pop(g))
                 pending[g], d_carry = vjp_fn(d_carry)
         else:  # SCATTER: fp32 pod-mean reduce-scatter, bucket order

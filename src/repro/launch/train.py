@@ -14,11 +14,11 @@ the run is data parallel over every device present):
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import SHAPES, get_config
@@ -121,6 +121,8 @@ class Trainer:
         # from the per-step `skipped_nonfinite` metric fraction
         self.skipped_nonfinite = 0.0
         self.last_metrics = {}
+        # blocking device-to-host transfers step_once has made (_read)
+        self.host_reads = 0
 
     def _put_state(self, state):
         """device_put a host ReplicaState with this run's shardings."""
@@ -148,14 +150,20 @@ class Trainer:
         from repro.train.train_step import _plan_of
         return _plan_of(self.model, self.averager)
 
+    def variant(self, t: int) -> str:
+        """The compiled step variant global step ``t`` runs: ``sync`` (the
+        tau-sync step) or ``group:<phase>``."""
+        if self.averager.sync_due(t):
+            return "sync"
+        return f"group:{self.averager.phase_for_step(t)}"
+
     def _step_fn(self, t: int):
-        sync = self.averager.sync_due(t)
-        phase = self.averager.phase_for_step(t)
-        key = ("sync",) if sync else ("group", phase)
+        key = self.variant(t)
         if key not in self._steps:
             self._steps[key] = build_train_step(
                 self.model, self.opt, self.averager, self.mesh,
-                phase=phase, sync=sync, microbatch=self.microbatch)
+                phase=self.averager.phase_for_step(t),
+                sync=self.averager.sync_due(t), microbatch=self.microbatch)
         return self._steps[key]
 
     def _put_batch(self, t: int):
@@ -164,6 +172,12 @@ class Trainer:
         return {k: jax.device_put(jnp.asarray(v), self._batch_sharding(
             jnp.asarray(v))) for k, v in nb.items()}
 
+    def _read(self, v) -> float:
+        """``float`` of a device value: one blocking device-to-host
+        transfer, counted in ``host_reads``."""
+        self.host_reads += 1
+        return float(v)
+
     def step_once(self, t: int) -> float:
         """Run global step ``t`` (data, variant dispatch, update); returns loss.
 
@@ -171,16 +185,27 @@ class Trainer:
         tau-sync schedule key off it, so an elastic driver that rebuilds
         the Trainer mid-run keeps passing its own monotonic counter.
         Callers outside :meth:`run` wrap in ``compat.set_mesh(self.mesh)``.
+
+        Three host spans name the step's parts in a profiler trace:
+        ``trainer.put_batch``, ``trainer.dispatch`` (stats ``variant`` and
+        ``step``) and ``trainer.read_metrics`` (stat ``host_reads``, the
+        counter as the span opens).
         """
         if self.fault_injector is not None:
             self.fault_injector.before_step(t)
-        batch = self._put_batch(t)
+        with TraceAnnotation("trainer.put_batch"):
+            batch = self._put_batch(t)
         step = self._step_fn(t)
-        self.state, metrics = step(self.state, batch)
-        self.last_metrics = {k: float(v) for k, v in metrics.items()}
-        self.skipped_nonfinite += \
-            self.last_metrics.get("skipped_nonfinite", 0.0) * self.n_dp
-        return float(metrics["loss"])
+        with TraceAnnotation("trainer.dispatch", variant=self.variant(t),
+                             step=t):
+            self.state, metrics = step(self.state, batch)
+        with TraceAnnotation("trainer.read_metrics",
+                             host_reads=self.host_reads):
+            self.last_metrics = {k: self._read(v) for k, v in metrics.items()}
+            self.skipped_nonfinite += \
+                self.last_metrics.get("skipped_nonfinite", 0.0) * self.n_dp
+            # the loss is on the host already: no transfer
+            return float(metrics["loss"])
 
     def step_hlo(self, t: int) -> str:
         """Compiled HLO text of the step variant global step ``t`` runs."""
@@ -191,18 +216,13 @@ class Trainer:
             ckpt_every=0):
         history = []
         with compat.set_mesh(self.mesh):
-            t0 = time.time()
             for t in range(steps):
                 loss = self.step_once(t)
                 history.append(loss)
                 if log_every and (t % log_every == 0 or t == steps - 1):
-                    dt = time.time() - t0
-                    tput = self.shape.global_batch * self.shape.seq_len \
-                        * (t + 1) / max(dt, 1e-9)
                     skip = (f" skipped_nonfinite {self.skipped_nonfinite:.0f}"
                             if self.skipped_nonfinite else "")
-                    print(f"step {t:5d} loss {loss:.4f} "
-                          f"({tput:,.0f} tok/s wall){skip}", flush=True)
+                    print(f"step {t:5d} loss {loss:.4f}{skip}", flush=True)
                 if ckpt_dir and ckpt_every and (t + 1) % ckpt_every == 0:
                     save_replica_state(
                         ckpt_dir, jax.device_get(self.state),

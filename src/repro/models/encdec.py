@@ -108,17 +108,22 @@ def _enc_kv(cfg, p, enc_out):
 
 
 def dec_layer(cfg, p, x, positions, enc_out):
-    h = tfm.norm_apply(cfg, x, p["ln1"])
-    q, k, v = tfm._qkv(cfg, p["attn"], h)
-    q = cm.apply_rope(q, positions, cfg.rope_theta)
-    k = cm.apply_rope(k, positions, cfg.rope_theta)
-    out = cm.blocked_attention(q, k, v, causal=True,
-                               block_q=cfg.attn_block_q,
-                               block_k=cfg.attn_block_k)
-    b, s = x.shape[:2]
-    x = x + out.reshape(b, s, -1) @ p["attn"]["wo"]
-    hx = tfm.norm_apply(cfg, x, p["ln_x"])
-    x = x + _cross_attn(cfg, p["cross"], hx, _enc_kv(cfg, p["cross"], enc_out))
+    # self- and cross-attention each under the scope that names them in
+    # the trace (model.attention_ms)
+    with jax.named_scope("attention"):
+        h = tfm.norm_apply(cfg, x, p["ln1"])
+        q, k, v = tfm._qkv(cfg, p["attn"], h)
+        q = cm.apply_rope(q, positions, cfg.rope_theta)
+        k = cm.apply_rope(k, positions, cfg.rope_theta)
+        out = cm.blocked_attention(q, k, v, causal=True,
+                                   block_q=cfg.attn_block_q,
+                                   block_k=cfg.attn_block_k)
+        b, s = x.shape[:2]
+        x = x + out.reshape(b, s, -1) @ p["attn"]["wo"]
+    with jax.named_scope("attention"):
+        hx = tfm.norm_apply(cfg, x, p["ln_x"])
+        x = x + _cross_attn(cfg, p["cross"], hx,
+                            _enc_kv(cfg, p["cross"], enc_out))
     x = x + tfm.mlp(cfg, p["mlp"], tfm.norm_apply(cfg, x, p["ln2"]))
     return x
 

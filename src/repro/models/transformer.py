@@ -137,15 +137,17 @@ def _qkv(cfg, p, h):
 
 
 def attn_layer(cfg, p, x, positions, window: Optional[int]):
-    h = norm_apply(cfg, x, p["ln1"])
-    q, k, v = _qkv(cfg, p["attn"], h)
-    q = cm.apply_rope(q, positions, cfg.rope_theta)
-    k = cm.apply_rope(k, positions, cfg.rope_theta)
-    out = cm.blocked_attention(q, k, v, causal=cfg.causal, window=window,
-                               block_q=cfg.attn_block_q,
-                               block_k=cfg.attn_block_k)
-    b, s = x.shape[:2]
-    x = x + out.reshape(b, s, -1) @ p["attn"]["wo"]
+    # the scope names the block's ops in the trace (model.attention_ms)
+    with jax.named_scope("attention"):
+        h = norm_apply(cfg, x, p["ln1"])
+        q, k, v = _qkv(cfg, p["attn"], h)
+        q = cm.apply_rope(q, positions, cfg.rope_theta)
+        k = cm.apply_rope(k, positions, cfg.rope_theta)
+        out = cm.blocked_attention(q, k, v, causal=cfg.causal, window=window,
+                                   block_q=cfg.attn_block_q,
+                                   block_k=cfg.attn_block_k)
+        b, s = x.shape[:2]
+        x = x + out.reshape(b, s, -1) @ p["attn"]["wo"]
     x = cm.wsc(x, None, None, None)          # replicated between blocks
     x = x + mlp(cfg, p["mlp"], norm_apply(cfg, x, p["ln2"]))
     x = cm.wsc(x, None, None, None)

@@ -338,7 +338,8 @@ def build_train_step(model, optimizer, averager, mesh, *, phase: int,
 
     def grads_and_metrics(params, batch):
         def loss_fn(p, mb):
-            loss, metrics = model.loss(p, mb, remat=remat)
+            with jax.named_scope("forward"):
+                loss, metrics = model.loss(p, mb, remat=remat)
             return loss, metrics
 
         def one(mb):
@@ -363,7 +364,8 @@ def build_train_step(model, optimizer, averager, mesh, *, phase: int,
         accumulator is shard-sized, not full-tree-sized.
         """
         def loss_fn(p, mb):
-            return model.loss(p, mb, remat=remat)
+            with jax.named_scope("forward"):
+                return model.loss(p, mb, remat=remat)
 
         def one(mb):
             full = plan.unshard_tree(shards)
@@ -395,6 +397,15 @@ def build_train_step(model, optimizer, averager, mesh, *, phase: int,
         grads, metrics, _ = one(batch)
         return grads, metrics
 
+    def exchange(tree):
+        """The averager's collective, under the ``sync`` or ``average``
+        scope that the trace's per-scope device time reads."""
+        if sync:
+            with jax.named_scope("sync"):
+                return averager.sync(tree)
+        with jax.named_scope("average"):
+            return averager.comm(tree, phase)
+
     def replica_fn(params, opt_state, batch):
         if streamed:
             grads, metrics = streamed_grads_and_metrics(params, batch)
@@ -404,22 +415,21 @@ def build_train_step(model, optimizer, averager, mesh, *, phase: int,
             grads, metrics = grads_and_metrics(params, batch)
 
         if averager.grad_comm:
-            grads = (averager.sync(grads) if sync
-                     else averager.comm(grads, phase))
+            grads = exchange(grads)
         # non-finite guard on the (pod-mean, for fsdp; group/global-mean,
         # for grad_comm averagers) gradients: a poisoned replica skips its
         # update and keeps averaging in its last good weights
-        finite = tree_all_finite(grads)
-        if sharded:
-            # psum-scattered pod-mean shards can carry the NaN on one
-            # slice only; every shard of the pod must agree to skip
-            finite = jax.lax.pmin(finite.astype(jnp.int32),
-                                  averager.sharding.shard_axis) > 0
-        new_params, new_opt, skipped = guarded_update(
-            optimizer, grads, opt_state, params, finite=finite)
+        with jax.named_scope("optimizer"):
+            finite = tree_all_finite(grads)
+            if sharded:
+                # psum-scattered pod-mean shards can carry the NaN on one
+                # slice only; every shard of the pod must agree to skip
+                finite = jax.lax.pmin(finite.astype(jnp.int32),
+                                      averager.sharding.shard_axis) > 0
+            new_params, new_opt, skipped = guarded_update(
+                optimizer, grads, opt_state, params, finite=finite)
         if not averager.grad_comm:
-            new_params = (averager.sync(new_params) if sync
-                          else averager.comm(new_params, phase))
+            new_params = exchange(new_params)
         metrics = dict(metrics)
         metrics["skipped_nonfinite"] = skipped
         metrics = {k: jax.lax.pmean(v.astype(jnp.float32), dp)
@@ -432,10 +442,20 @@ def build_train_step(model, optimizer, averager, mesh, *, phase: int,
     def step(state, batch):
         p, o, m = replica_fn(squeeze(state.params), squeeze(state.opt_state),
                              batch)
-        new_state = ReplicaState(
-            expand(p), expand(o), state.step + 1,
-            jnp.asarray(-1 if sync else phase, jnp.int32))
+        # the optimiser's elementwise update fuses into this write of the
+        # new state, and a fusion takes the op_name of its last op
+        with jax.named_scope("optimizer"):
+            new_state = ReplicaState(
+                expand(p), expand(o), state.step + 1,
+                jnp.asarray(-1 if sync else phase, jnp.int32))
         return new_state, m
+
+    # The module takes this name (``jit_group_step``, ``jit_sync_step``),
+    # which tells the variants apart in a trace.  The persistent
+    # compilation cache keys a module by its name and its ops with their
+    # metadata stripped: a step cached under the same name before its
+    # scopes moved comes back with the old ``op_name``s.
+    step.__name__ = "sync_step" if sync else "group_step"
 
     state_specs = replica_state_specs(model, optimizer, averager, mesh)
     sm = compat.shard_map(
