@@ -12,11 +12,11 @@ import functools
 
 import jax
 
-from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.group_average import group_average_combine as _combine
 from repro.kernels.group_average import (group_average_combine_multi
                                          as _combine_multi)
 from repro.kernels.rglru_scan import rglru_scan as _rglru
+from repro.kernels.splash_attention import splash_attention as _splash
 
 
 def _default_interpret() -> bool:
@@ -27,17 +27,17 @@ def _resolve(interpret) -> bool:
     return _default_interpret() if interpret is None else bool(interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k", "interpret"))
-def _flash_jit(q, k, v, *, causal, window, block_q, block_k, interpret):
-    return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
-                  block_k=block_k, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+                                             "interpret"))
+def _splash_jit(q, k, v, *, causal, block_q, block_k, interpret):
+    return _splash(q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+                   interpret=interpret)
 
 
-def flash_attention(q, k, v, *, causal=True, window=None, block_q=128,
-                    block_k=128, interpret=None):
-    return _flash_jit(q, k, v, causal=causal, window=window, block_q=block_q,
-                      block_k=block_k, interpret=_resolve(interpret))
+def splash_attention(q, k, v, *, causal=True, block_q=512, block_k=1024,
+                     interpret=None):
+    return _splash_jit(q, k, v, causal=causal, block_q=block_q,
+                       block_k=block_k, interpret=_resolve(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("inv_s", "interpret"))

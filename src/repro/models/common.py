@@ -2,20 +2,24 @@
 decode-with-cache), losses, init + sharding-spec helpers.
 
 Memory discipline: training/prefill attention never materialises the full
-(S, S) score matrix — ``blocked_attention`` runs an online-softmax scan over
-KV blocks (the jnp analogue of the Pallas flash kernel; identical FLOPs/bytes
-at roofline granularity). Sliding-window layers slice only the in-window KV
-blocks so local attention costs O(S*W), not O(S^2).
+(S, S) score matrix. ``attention`` routes long unwindowed self-attention on a
+TPU to the splash Pallas kernel (kernels/splash_attention.py: flash forward
+and backward, causal blocks skipped) and everything else to
+``blocked_attention``, an online-softmax scan over KV blocks. Sliding-window
+layers slice only the in-window KV blocks so local attention costs O(S*W),
+not O(S^2).
 """
 
 from __future__ import annotations
 
+import collections
+import functools
 import math
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro import compat
 
@@ -230,6 +234,82 @@ def blocked_attention(q, k, v, *, causal: bool = True,
                        (jnp.arange(nq), q_blocks))           # (nq,B,H,bq,hd)
     out = outs.transpose(1, 0, 3, 2, 4).reshape(b, nq * block_q, h, hd)
     return out[:, :sq].astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Attention dispatch (train / prefill): the splash kernel where shapes allow
+# ---------------------------------------------------------------------------
+
+# Trace-time count of attention calls by the path they were routed to
+# ("kernel" or "blocked"), for tests and for checking a compiled program.
+ATTENTION_PATHS: collections.Counter = collections.Counter()
+
+KERNEL_MIN_SEQ = 512
+
+
+def attention_path(q_shape, k_shape, *, window, q_offset, block_q: int,
+                   block_k: int, backend: str, mesh) -> str:
+    """``"kernel"`` (the splash Pallas kernel) or ``"blocked"`` for one call.
+
+    A pure function of the shapes, the backend's name and the (abstract)
+    mesh.  The kernel takes self-attention from position 0 with no window,
+    at least ``KERNEL_MIN_SEQ`` long, tiled evenly by the clipped lane-aligned
+    tiles, with a lane-wide head, on a TPU whose ``model`` axis (if any) is 1.
+    """
+    _, sq, _, hd = q_shape
+    sk = k_shape[1]
+    tq, tk = min(block_q, sq), min(block_k, sk)
+    model = 1 if mesh is None or mesh.empty else dict(mesh.shape).get("model", 1)
+    fits = (backend == "tpu" and window is None
+            and isinstance(q_offset, int) and q_offset == 0
+            and sq == sk and sq >= KERNEL_MIN_SEQ
+            and sq % tq == 0 and sq % tk == 0
+            and tq % 128 == 0 and tk % 128 == 0
+            and hd % 128 == 0 and model <= 1)
+    return "kernel" if fits else "blocked"
+
+
+def _manual_over_auto_axes(fn, mesh, *xs):
+    """Call ``fn(*xs)`` manual over every mesh axis (a Mosaic kernel cannot be
+    partitioned by GSPMD; ``core/plan`` does the same for the combine kernel).
+
+    The leading (batch) dim is split over the remaining Auto axes where it
+    divides; the dispatcher only comes here with ``model`` at 1.
+    """
+    auto = () if mesh.empty else tuple(
+        a for a, t in zip(mesh.axis_names, mesh.axis_types)
+        if t != AxisType.Manual)
+    if not auto:
+        return fn(*xs)
+    n = math.prod(mesh.shape[a] for a in auto)
+    spec = P(auto) if xs[0].shape[0] % n == 0 else P()
+    return compat.shard_map(fn, mesh=mesh, in_specs=(spec,) * len(xs),
+                            out_specs=spec,
+                            axis_names=set(mesh.axis_names))(*xs)
+
+
+def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
+              block_q: int = 512, block_k: int = 1024, q_offset: int = 0):
+    """Train / prefill attention; q (B,Sq,H,hd), k/v (B,Sk,KH,hd).
+
+    Routes by ``attention_path`` to the splash kernel (tiles ``block_q`` x
+    ``block_k`` clipped to S) or to ``blocked_attention``, which takes the
+    same arguments.
+    """
+    mesh = compat.get_abstract_mesh()
+    path = attention_path(q.shape, k.shape, window=window, q_offset=q_offset,
+                          block_q=block_q, block_k=block_k,
+                          backend=jax.default_backend(), mesh=mesh)
+    ATTENTION_PATHS[path] += 1
+    if path == "blocked":
+        return blocked_attention(q, k, v, causal=causal, window=window,
+                                 block_q=block_q, block_k=block_k,
+                                 q_offset=q_offset)
+    from repro.kernels import ops      # Pallas loads only where it runs
+    kernel = functools.partial(ops.splash_attention, causal=causal,
+                               block_q=block_q, block_k=block_k)
+    with jax.named_scope("attention_kernel"):
+        return _manual_over_auto_axes(kernel, mesh, q, k, v)
 
 
 def decode_attention(q, k_cache, v_cache, *, length, window: Optional[int] = None,

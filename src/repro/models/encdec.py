@@ -94,9 +94,9 @@ def _cross_attn(cfg, p, x, enc_kv):
     b, s, _ = x.shape
     q = (x @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.hd)
     ek, ev = enc_kv
-    out = cm.blocked_attention(q, ek, ev, causal=False,
-                               block_q=cfg.attn_block_q,
-                               block_k=cfg.attn_block_k)
+    out = cm.attention(q, ek, ev, causal=False,
+                       block_q=cfg.attn_block_q,
+                       block_k=cfg.attn_block_k)
     return out.reshape(b, s, -1) @ p["wo"]
 
 
@@ -115,9 +115,9 @@ def dec_layer(cfg, p, x, positions, enc_out):
         q, k, v = tfm._qkv(cfg, p["attn"], h)
         q = cm.apply_rope(q, positions, cfg.rope_theta)
         k = cm.apply_rope(k, positions, cfg.rope_theta)
-        out = cm.blocked_attention(q, k, v, causal=True,
-                                   block_q=cfg.attn_block_q,
-                                   block_k=cfg.attn_block_k)
+        out = cm.attention(q, k, v, causal=True,
+                           block_q=cfg.attn_block_q,
+                           block_k=cfg.attn_block_k)
         b, s = x.shape[:2]
         x = x + out.reshape(b, s, -1) @ p["attn"]["wo"]
     with jax.named_scope("attention"):
@@ -176,9 +176,9 @@ def prefill(cfg, params, tokens, enc_input=None, max_len=None,
         q, k, v = tfm._qkv(cfg, p["attn"], h)
         q = cm.apply_rope(q, positions, cfg.rope_theta)
         k = cm.apply_rope(k, positions, cfg.rope_theta)
-        out = cm.blocked_attention(q, k, v, causal=True,
-                                   block_q=cfg.attn_block_q,
-                                   block_k=cfg.attn_block_k)
+        out = cm.attention(q, k, v, causal=True,
+                           block_q=cfg.attn_block_q,
+                           block_k=cfg.attn_block_k)
         x = x + out.reshape(b, s, -1) @ p["attn"]["wo"]
         hx = tfm.norm_apply(cfg, x, p["ln_x"])
         ek, ev = _enc_kv(cfg, p["cross"], enc_out)

@@ -423,10 +423,10 @@ def moe_attn_layer(cfg, p, x, positions, capacity=None):
     q, kk, v = tfm._qkv(cfg, p["attn"], h)
     q = cm.apply_rope(q, positions, cfg.rope_theta)
     kk = cm.apply_rope(kk, positions, cfg.rope_theta)
-    out = cm.blocked_attention(q, kk, v, causal=True,
-                               window=cfg.sliding_window,
-                               block_q=cfg.attn_block_q,
-                               block_k=cfg.attn_block_k)
+    out = cm.attention(q, kk, v, causal=True,
+                       window=cfg.sliding_window,
+                       block_q=cfg.attn_block_q,
+                       block_k=cfg.attn_block_k)
     b, s = x.shape[:2]
     x = x + out.reshape(b, s, -1) @ p["attn"]["wo"]
     ffn_in = tfm.norm_apply(cfg, x, p["ln2"])
@@ -522,10 +522,10 @@ def prefill(cfg, params, tokens, max_len=None, prefix_embeds=None,
         q, kk, v = tfm._qkv(cfg, p["attn"], h)
         q = cm.apply_rope(q, positions, cfg.rope_theta)
         kk = cm.apply_rope(kk, positions, cfg.rope_theta)
-        out = cm.blocked_attention(q, kk, v, causal=True,
-                                   window=cfg.sliding_window,
-                                   block_q=cfg.attn_block_q,
-                                   block_k=cfg.attn_block_k)
+        out = cm.attention(q, kk, v, causal=True,
+                           window=cfg.sliding_window,
+                           block_q=cfg.attn_block_q,
+                           block_k=cfg.attn_block_k)
         x = x + out.reshape(b, s, -1) @ p["attn"]["wo"]
         h2 = tfm.norm_apply(cfg, x, p["ln2"])
         if moe_layer:

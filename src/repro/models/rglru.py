@@ -233,9 +233,9 @@ def prefill(cfg, params, tokens, max_len=None, prefix_embeds=None,
         q, k, v = tfm._qkv(cfg, p["attn"], h)
         q = cm.apply_rope(q, positions, cfg.rope_theta)
         k = cm.apply_rope(k, positions, cfg.rope_theta)
-        out = cm.blocked_attention(q, k, v, causal=True, window=ATTN_WINDOW,
-                                   block_q=cfg.attn_block_q,
-                                   block_k=cfg.attn_block_k)
+        out = cm.attention(q, k, v, causal=True, window=ATTN_WINDOW,
+                           block_q=cfg.attn_block_q,
+                           block_k=cfg.attn_block_k)
         x = x + out.reshape(b, s, -1) @ p["attn"]["wo"]
         x = x + tfm.mlp(cfg, p["mlp"], tfm.norm_apply(cfg, x, p["ln2"]))
         j = jnp.arange(win)

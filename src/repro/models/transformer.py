@@ -143,9 +143,9 @@ def attn_layer(cfg, p, x, positions, window: Optional[int]):
         q, k, v = _qkv(cfg, p["attn"], h)
         q = cm.apply_rope(q, positions, cfg.rope_theta)
         k = cm.apply_rope(k, positions, cfg.rope_theta)
-        out = cm.blocked_attention(q, k, v, causal=cfg.causal, window=window,
-                                   block_q=cfg.attn_block_q,
-                                   block_k=cfg.attn_block_k)
+        out = cm.attention(q, k, v, causal=cfg.causal, window=window,
+                           block_q=cfg.attn_block_q,
+                           block_k=cfg.attn_block_k)
         b, s = x.shape[:2]
         x = x + out.reshape(b, s, -1) @ p["attn"]["wo"]
     x = cm.wsc(x, None, None, None)          # replicated between blocks
@@ -368,9 +368,9 @@ def prefill(cfg, params, tokens, max_len: Optional[int] = None,
         q, k, v = _qkv(cfg, p["attn"], h)
         q = cm.apply_rope(q, positions, cfg.rope_theta)
         k = cm.apply_rope(k, positions, cfg.rope_theta)
-        out = cm.blocked_attention(q, k, v, causal=True, window=window,
-                                   block_q=cfg.attn_block_q,
-                                   block_k=cfg.attn_block_k)
+        out = cm.attention(q, k, v, causal=True, window=window,
+                           block_q=cfg.attn_block_q,
+                           block_k=cfg.attn_block_k)
         x = x + out.reshape(b, s, -1) @ p["attn"]["wo"]
         x = x + mlp(cfg, p["mlp"], norm_apply(cfg, x, p["ln2"]))
         if window is not None:

@@ -15,44 +15,107 @@ def randn(*shape, dtype=jnp.float32):
     return jnp.asarray(RNG.standard_normal(shape), dtype)
 
 
-ATTN_CASES = [
-    # (b, sq, sk, h, kh, hd, causal, window, dtype)
-    (2, 128, 128, 4, 2, 64, True, None, jnp.float32),
-    (1, 256, 256, 4, 4, 32, True, 64, jnp.float32),
-    (2, 100, 100, 2, 1, 64, False, None, jnp.float32),
-    (1, 128, 256, 4, 2, 128, True, None, jnp.float32),
-    (1, 64, 64, 2, 2, 64, True, None, jnp.bfloat16),
-    (1, 72, 72, 3, 1, 48, True, 16, jnp.float32),   #非-128-aligned
+# Splash attention (kernels/splash_attention.py) against the model's
+# blocked_attention and the naive oracle: output and q/k/v gradients, causal
+# GQA with rep 2 and hd 128, tiles clipped to S.
+SPLASH_CASES = [
+    # (b, s, h, kh, block_q, block_k, causal, dtype)
+    (1, 256, 4, 2, 128, 256, True, jnp.float32),
+    (2, 256, 4, 2, 128, 128, True, jnp.float32),
+    (1, 512, 4, 2, 256, 256, True, jnp.float32),
+    (1, 256, 4, 2, 512, 1024, False, jnp.float32),   # tiles clipped to S
+    (1, 256, 4, 2, 128, 256, True, jnp.bfloat16),
+    (2, 256, 4, 2, 128, 128, True, jnp.bfloat16),
+    (1, 512, 4, 2, 256, 256, True, jnp.bfloat16),
+    (2, 512, 4, 2, 256, 128, True, jnp.bfloat16),
+]
+# Relative error, in the norm of the whole tensor, against the float32 oracle
+# on the same (rounded) inputs.  float32: accumulation order only.  bfloat16:
+# the kernel's MXU operands (q scaled, p) round to bfloat16, ~2^-8 each.
+SPLASH_TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
+
+
+def _rel_err(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def _out_and_grads(fn, q, k, v, cot):
+    def loss(q, k, v):
+        return jnp.sum(fn(q, k, v).astype(jnp.float32) * cot)
+    return fn(q, k, v), jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("case", SPLASH_CASES,
+                         ids=lambda c: "-".join(map(str, c[:7])) + "-"
+                         + jnp.dtype(c[7]).name)
+def test_splash_attention_matches_blocked_and_ref(case):
+    from repro.models.common import blocked_attention
+    b, s, h, kh, bq, bk, causal, dtype = case
+    hd = 128
+    q, k, v = (randn(b, s, n, hd, dtype=dtype) for n in (h, kh, kh))
+    cot = randn(b, s, h, hd)
+    tiles = dict(block_q=bq, block_k=bk)
+    got = _out_and_grads(lambda q, k, v: ops.splash_attention(
+        q, k, v, causal=causal, **tiles), q, k, v, cot)
+    blocked = _out_and_grads(lambda q, k, v: blocked_attention(
+        q, k, v, causal=causal, **tiles), q, k, v, cot)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want = _out_and_grads(lambda q, k, v: ref.flash_attention_ref(
+        q, k, v, causal=causal), *f32, cot)
+    tol = SPLASH_TOL[dtype]
+    assert got[0].shape == q.shape and got[0].dtype == dtype
+    for name, g, bl, w in zip(("out", "dq", "dk", "dv"),
+                              (got[0], *got[1]), (blocked[0], *blocked[1]),
+                              (want[0], *want[1])):
+        assert g.shape == w.shape, name
+        assert _rel_err(g, w) < tol, (name, _rel_err(g, w))
+        assert _rel_err(g, bl) < tol, (name, _rel_err(g, bl))
+
+
+QWEN3_Q, QWEN3_KV = (4, 2048, 16, 128), (4, 2048, 8, 128)
+ROUTES = [
+    # (id, q shape, k shape, kwargs, backend, mesh, path)
+    ("qwen3", QWEN3_Q, QWEN3_KV, {}, "tpu", None, "kernel"),
+    ("qwen3-mesh-1x1", QWEN3_Q, QWEN3_KV, {}, "tpu", ((1, 1), ("data", "model")),
+     "kernel"),
+    ("qwen3-cpu", QWEN3_Q, QWEN3_KV, {}, "cpu", None, "blocked"),
+    ("wmt", (48, 64, 8, 64), (48, 64, 8, 64), {}, "tpu", None, "blocked"),
+    ("windowed", QWEN3_Q, QWEN3_KV, {"window": 1024}, "tpu", None, "blocked"),
+    ("q-offset", QWEN3_Q, QWEN3_KV, {"q_offset": 512}, "tpu", None, "blocked"),
+    ("sq-ne-sk", (4, 1024, 16, 128), QWEN3_KV, {}, "tpu", None, "blocked"),
+    ("model-axis-2", QWEN3_Q, QWEN3_KV, {}, "tpu",
+     ((1, 2), ("data", "model")), "blocked"),
 ]
 
 
-@pytest.mark.parametrize("case", ATTN_CASES)
-def test_flash_attention_allclose(case):
-    b, sq, sk, h, kh, hd, causal, window, dtype = case
-    q = randn(b, sq, h, hd, dtype=dtype)
-    k = randn(b, sk, kh, hd, dtype=dtype)
-    v = randn(b, sk, kh, hd, dtype=dtype)
-    out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              block_q=64, block_k=64)
-    want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
-    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-4
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol)
+@pytest.mark.parametrize("case", ROUTES, ids=[r[0] for r in ROUTES])
+def test_attention_path(case):
+    from jax.sharding import AbstractMesh
+    from repro.models.common import attention_path
+    _, qs, ks, kw, backend, mesh, path = case
+    mesh = None if mesh is None else AbstractMesh(*mesh)
+    kw = {"window": None, "q_offset": 0, **kw}
+    assert attention_path(qs, ks, block_q=512, block_k=1024, backend=backend,
+                          mesh=mesh, **kw) == path
 
 
-def test_flash_attention_matches_model_blocked_attention():
-    from repro.models.common import blocked_attention
-    q = randn(2, 96, 4, 64)
-    k = randn(2, 96, 2, 64)
-    v = randn(2, 96, 2, 64)
-    for window in (None, 32):
-        a = ops.flash_attention(q, k, v, causal=True, window=window,
-                                block_q=32, block_k=32)
-        bopt = blocked_attention(q, k, v, causal=True, window=window,
-                                 block_q=32, block_k=32)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(bopt),
-                                   rtol=2e-4, atol=2e-4)
+def test_attention_counts_and_runs_what_it_routed(monkeypatch):
+    """On a backend named TPU the dispatcher routes a long call to the kernel
+    (run here in interpret mode) and a short one to blocked_attention, counts
+    each, and both agree with blocked_attention."""
+    from repro.models import common as cm
+    monkeypatch.setattr(ops, "_default_interpret", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = dict(cm.ATTENTION_PATHS)
+    for s, path in ((512, "kernel"), (64, "blocked")):
+        q, k, v = (randn(1, s, n, 128) for n in (4, 2, 2))
+        out = cm.attention(q, k, v, block_q=256, block_k=512)
+        grown = {p: cm.ATTENTION_PATHS[p] - before.get(p, 0)
+                 for p in ("kernel", "blocked")}
+        assert grown == {"kernel": 1, "blocked": int(path == "blocked")}
+        want = cm.blocked_attention(q, k, v, block_q=256, block_k=512)
+        assert _rel_err(out, want) < SPLASH_TOL[jnp.float32]
 
 
 @settings(max_examples=20, deadline=None)

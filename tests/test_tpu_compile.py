@@ -106,3 +106,95 @@ def test_four_chip_wagma_step_compiles_natively(topo, native, policy):
         hlo = step.lower(state, batch).compile().as_text()
     assert "collective-permute" in hlo
     assert "tpu_custom_call" in hlo
+
+
+def _score_shaped_updates(hlo: str, block_q: int, block_k: int) -> list:
+    """``dynamic-update-slice`` ops whose result ends in a score block."""
+    import re
+    tail = re.compile(rf"\[[0-9,]*{block_q},{block_k}\]")
+    return [ln for ln in hlo.splitlines()
+            if "dynamic-update-slice(" in ln and tail.search(ln.split("=")[1])]
+
+
+def _qwen3_layer(b: int = 1, s: int = 2048):
+    """One qwen3-0.6b attention layer at real widths (16/8 heads, hd 128)
+    and a fresh function for its gradients under ``jax.remat``: a new
+    function object each call, so each route is traced anew."""
+    from repro.configs import get_config
+    from repro.models import transformer as tfm
+    cfg = get_config("qwen3-0.6b").variant(head_dim=128, n_layers=1)
+
+    def make_grads():
+        def grads(p, x, cot):
+            pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+            layer = jax.remat(lambda p, x: tfm.attn_layer(cfg, p, x, pos,
+                                                          None))
+            return jax.grad(lambda p, x: jnp.sum(
+                layer(p, x).astype(jnp.float32) * cot), argnums=(0, 1))(p, x)
+        return grads
+
+    def init(key):
+        kp, kx, kc = jax.random.split(key, 3)
+        x = jax.random.normal(kx, (b, s, cfg.d_model), jnp.float32)
+        return (tfm.init_layer(cfg, kp, jnp.bfloat16), x.astype(jnp.bfloat16),
+                jax.random.normal(kc, (b, s, cfg.d_model), jnp.float32))
+    return cfg, make_grads, init
+
+
+def test_qwen3_attention_layer_compiles_through_the_kernel(topo, native,
+                                                            monkeypatch):
+    """Routed as on a TPU, the layer's gradients compile for a described v5e
+    with the splash kernel in them and no score-shaped buffer stacked for
+    the backward; routed to the blocked path they stack such buffers, which
+    keeps the check honest."""
+    from repro.models import common as cm
+    cfg, make_grads, init = _qwen3_layer()
+    one = SingleDeviceSharding(topo.devices[0])
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(init, jax.random.PRNGKey(0)))
+    rule = cm.attention_path
+
+    def compile_as(backend):
+        monkeypatch.setattr(cm, "attention_path", lambda *a, **kw: rule(
+            *a, **{**kw, "backend": backend}))
+        return jax.jit(make_grads()).lower(*args).compile().as_text()
+
+    before = cm.ATTENTION_PATHS["kernel"]
+    hlo = compile_as("tpu")
+    assert cm.ATTENTION_PATHS["kernel"] > before
+    assert "tpu_custom_call" in hlo
+    assert _score_shaped_updates(hlo, cfg.attn_block_q, cfg.attn_block_k) == []
+    hlo = compile_as("cpu")
+    assert "tpu_custom_call" not in hlo
+    assert _score_shaped_updates(hlo, cfg.attn_block_q, cfg.attn_block_k)
+
+
+def test_qwen3_attention_layer_runs_the_kernel_on_tpu(monkeypatch):
+    """On a TPU the layer's gradients run through the splash kernel, with no
+    score-shaped buffer stacked for the backward, and match the blocked
+    path's within bfloat16 tolerance."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("runs on a TPU")
+    import numpy as np
+    from repro.models import common as cm
+    cfg, make_grads, init = _qwen3_layer()
+    args = init(jax.random.PRNGKey(0))
+
+    def run():
+        compiled = jax.jit(make_grads()).lower(*args).compile()
+        return compiled(*args), compiled.as_text()
+
+    before = cm.ATTENTION_PATHS["kernel"]
+    got, hlo = run()
+    assert cm.ATTENTION_PATHS["kernel"] > before
+    assert "tpu_custom_call" in hlo
+    assert _score_shaped_updates(hlo, cfg.attn_block_q, cfg.attn_block_k) == []
+
+    monkeypatch.setattr(cm, "attention_path", lambda *a, **kw: "blocked")
+    want, hlo = run()
+    assert "tpu_custom_call" not in hlo
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        err = np.linalg.norm(g - w) / max(np.linalg.norm(w), 1e-30)
+        assert err < 3e-2, err
