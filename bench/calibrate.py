@@ -8,11 +8,12 @@ For every seed: the program's checked steps, exactly as a run of the cell
 makes them (``harness.build`` and ``harness.program_readings``), and the
 reference's; the three numbers of ``correct.gaps`` between them are the
 program's readings.  For the first ``--control-seeds`` seeds also the
-control (the reference in int8, in the program's place) and the fault a
-one-chip cell can have beside a state left unchanged, planted in the
-reference put in the program's place: half of each worker's batch left
-out.  A state left unchanged reads 1 on ``change_gap`` by construction and
-needs no run.  One JSON line per reading.
+control (the reference in int8, in the program's place) and the faults a
+cell can have beside a state left unchanged, planted in the reference put
+in the program's place: half of each worker's batch left out, and on
+several chips the exchange between them left out.  A state left unchanged
+reads 1 on ``change_gap`` by construction and needs no run.  One JSON line
+per reading.
 """
 
 import time
@@ -64,7 +65,10 @@ def calibrate(workload, seeds, control_seeds, emit, *,
                                 for k in ("grad_norms", "change_norms")}})
         if i >= control_seeds:
             continue
-        for mode, fault in (("int8", None), ("reference", "half_batch")):
+        others = [("int8", None), ("reference", "half_batch")]
+        if r["cell"]["chips"] > 1:
+            others.append(("reference", "no_exchange"))
+        for mode, fault in others:
             other = harness.reference_for(jax, r, data, t0, seed, mode=mode,
                                           fault=fault)
             emit({"seed": seed, "kind": fault or mode,

@@ -13,8 +13,10 @@ Set-up drives the program's own train-step object through its first
 After the window the plain reference (``reference/<family>.py``) follows
 the same steps from the same weights and batches: float32 at the highest
 precision, parameters stored in the configuration's dtype between steps,
-SGD momentum, and WAGMA's group averaging and tau-sync by the paper's
-schedule.  Three numbers are compared, each against its limit:
+SGD momentum, and the cell's averager (``AVERAGERS``): WAGMA's group
+averaging and tau-sync by the paper's schedule, or allreduce's float32
+mean of the replicas' gradients, one update applied on every replica.
+Three numbers are compared, each against its limit:
 
 * ``loss_gap``: the largest relative gap between a step's losses;
 * ``grad_gap``: over leaves and replicas, the largest gap between the
@@ -40,6 +42,9 @@ from bench.reference import common as C
 CHECKED_STEPS = 3
 NOUGHT = 1e-3
 NUMBERS = ("loss_gap", "grad_gap", "change_gap")
+# averagers the reference follows: WAGMA averages the models after each
+# update; allreduce averages the gradients before it
+AVERAGERS = ("wagma", "allreduce")
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
@@ -120,14 +125,19 @@ def reference_readings(family, spec: dict, train: dict, params0, batches,
 
     ``params0``: the benchmark's initial weights (one replica, storage
     dtype); ``batches``: the checked steps' global batches (numpy, rows
-    chip-major).  ``mode`` picks the matmul precision; ``fault`` plants
-    "half_batch" (each replica's mean over the first half of its rows) for
-    calibrating the limits.
+    chip-major).  ``mode`` picks the matmul precision; ``fault`` plants,
+    for calibrating the limits, "half_batch" (each replica's mean over the
+    first half of its rows) or "no_exchange" (no averaging between the
+    replicas).
     """
+    if train["averager"] not in AVERAGERS:
+        raise ValueError(f"the reference follows {AVERAGERS}, not "
+                         f"{train['averager']!r}")
     mm = C.matmul(mode)
     dtype = jax.tree.leaves(params0)[0].dtype
     lr, mu = train["learning_rate"], train["momentum"]
     step = jax.jit(functools.partial(family.loss_and_grad, spec, mm))
+    exchange = fault != "no_exchange"
 
     @jax.jit
     def update(p, g, m):
@@ -151,11 +161,15 @@ def reference_readings(family, spec: dict, train: dict, params0, batches,
             loss, g = step(f32, b)
             step_losses.append(float(loss))
             grads.append(g)
-            reps[r], moms[r] = update(reps[r], g, moms[r])
+        if exchange and train["averager"] == "allreduce":
+            grads = [jax.tree.map(lambda *gs: sum(gs) / chips, *grads)] * chips
         if i == 0:
             grad_norms = _stack_norms(grads)
+        for r in range(chips):
+            reps[r], moms[r] = update(reps[r], grads[r], moms[r])
         del grads
-        reps = average(reps, t0 + i, train, dtype)
+        if exchange and train["averager"] == "wagma":
+            reps = average(reps, t0 + i, train, dtype)
         losses.append(float(np.mean(step_losses)))
     init = jax.tree.map(lambda a: a[None], params0)
     change = host(change_norms(_stack(reps), init))

@@ -2,9 +2,13 @@
 
 Everything that belongs to a cell is found by name in ``BENCHMARK.json``:
 its configuration (``configs/<config>.json``, with the reference family it
-names under ``reference/``), its traffic mix (``traffic/<traffic>.json``),
-its limits (``limits/<workload>.json``) and each metric it reports
-(``metrics/<metric>.py``).  Adding a cell adds files; nothing here changes.
+names under ``reference/``, which also counts the family's FLOPs and cuts
+it to a test size), its traffic mix (``traffic/<traffic>.json``), its
+limits (``limits/<workload>.json``), the keys of its configuration's
+``train`` block that it overrides (``cells/<workload>.json``, where there
+is one: the averager, its group size, tau) and each metric it reports
+(``metrics/<metric>.py``).  Adding a cell adds files; nothing here
+changes.
 
 A run:
 
@@ -12,12 +16,12 @@ A run:
    program's ``Trainer`` built around them, the traffic's batches made,
    put on the chips and installed through ``Trainer.batch_fn``, then the
    first ``correct.CHECKED_STEPS`` steps through ``Trainer.step_once``.  These
-   start at global step ``first_checked_step`` (tau - 2: a group step,
-   the tau-sync and a group step), compile every step variant the window
-   uses and are the steps the reference follows;
+   start at global step ``first_checked_step`` (under WAGMA tau - 2: a
+   group step, the tau-sync and a group step), compile every step variant
+   the window uses and are the steps the reference follows;
 2. the window: ``step_once`` for ``--seconds`` seconds (with ``--trace 1``,
-   one whole tau period under the profiler instead), the global step
-   continuing;
+   tau steps under the profiler instead, a whole period of WAGMA's
+   schedule), the global step continuing;
 3. after the window: peak memory, the program freed, then the reference.
 """
 
@@ -81,10 +85,21 @@ def resolve(manifest: dict, workload: str) -> dict:
     return {
         "cell": cell,
         "spec": spec,
+        "train": {**spec["train"], **cell_overrides(workload)["train"]},
         "traffic": traffic,
         "end_to_end": [m for m in manifest["end_to_end"] if listed(m)],
         "per_layer": [m for m in manifest["per_layer"] if listed(m)],
     }
+
+
+def cell_overrides(workload: str) -> dict:
+    """``cells/<workload>.json``: what the cell sets beside its manifest
+    entry (its ``train`` keys); nothing where the cell has no such file."""
+    path = os.path.join(HERE, "cells", f"{workload}.json")
+    if not os.path.exists(path):
+        return {"train": {}}
+    with open(path) as f:
+        return json.load(f)
 
 
 def metric_reader(name: str):
@@ -169,9 +184,10 @@ def make_weights(jax, family, spec, mesh, chips: int, seed: int):
 
 
 def first_checked_step(train: dict) -> int:
-    """Global step of the first checked step: the checked steps are a
-    group step, the tau-sync and a group step."""
-    return train["tau"] - 2
+    """Global step of the first checked step.  Under WAGMA the checked
+    steps are a group step, the tau-sync and a group step; allreduce runs
+    one step variant, with no sync, from step 0."""
+    return train["tau"] - 2 if train["averager"] == "wagma" else 0
 
 
 def build(jax, r: dict, seed: int, devices):
@@ -181,18 +197,19 @@ def build(jax, r: dict, seed: int, devices):
     from repro.launch.train import Trainer
     from repro.optim import sgd
 
-    cell, spec, mix, train = r["cell"], r["spec"], r["traffic"], \
-        r["spec"]["train"]
+    cell, spec, mix, train = r["cell"], r["spec"], r["traffic"], r["train"]
     chips = cell["chips"]
-    if train["averager"] != "wagma" or train["optimizer"] != "sgd":
-        raise ValueError("the reference follows WAGMA with SGD momentum only")
+    if train["averager"] not in CK.AVERAGERS or train["optimizer"] != "sgd":
+        raise ValueError(f"the reference follows {CK.AVERAGERS} with SGD "
+                         f"momentum only, not {train['averager']!r} with "
+                         f"{train['optimizer']!r}")
     mesh = make_mesh((chips, 1), ("data", "model"), devices=devices)
     family = family_module(spec)
     params = make_weights(jax, family, spec, mesh, chips, seed)
     opt = sgd(train["learning_rate"], momentum=train["momentum"])
     opt_state = jax.jit(jax.vmap(opt.init))(params)
-    tr = Trainer(program_config(spec), mesh, averager="wagma",
-                 group_size=train["group_size"], tau=train["tau"],
+    tr = Trainer(program_config(spec), mesh, averager=train["averager"],
+                 group_size=train.get("group_size"), tau=train["tau"],
                  optimizer="sgd", learning_rate=train["learning_rate"],
                  momentum=train["momentum"], seq_len=mix["seq_len"],
                  global_batch=mix["batch_per_worker"] * chips, seed=seed,
@@ -219,11 +236,13 @@ def variant(tr, t: int) -> tuple:
     return ("sync",) if av.sync_due(t) else ("group", av.phase_for_step(t))
 
 
-def warm_rest(tr, t0: int) -> int:
-    """Steps past the checked ones until every variant has run on a state
-    that a step made (a variant's first call on such a state still takes
-    JAX's slow dispatch path once); returns the window's first step."""
-    need = {("sync",)} | {("group", p) for p in range(tr.averager.n_phases)}
+def warm_rest(tr, t0: int, tau: int) -> int:
+    """Steps past the checked ones until every variant that the schedule
+    runs (over ``tau`` periods of its phases) has run on a state that a
+    step made (a variant's first call on such a state still takes JAX's
+    slow dispatch path once); returns the window's first step."""
+    need = {variant(tr, u)
+            for u in range(t0, t0 + tau * tr.averager.n_phases)}
     t = t0 + CK.CHECKED_STEPS
     seen = {variant(tr, u) for u in range(t0 + 1, t)}
     while need - seen:
@@ -276,7 +295,7 @@ def reference_for(jax, r, data, t0, seed, mode="reference", fault=None):
     params0 = jax.jit(lambda k: C.init_tree(family.shapes(r["spec"]), k))(
         C.seed_key(seed))
     return CK.reference_readings(
-        family, r["spec"], r["spec"]["train"], params0,
+        family, r["spec"], r["train"], params0,
         data[:CK.CHECKED_STEPS], r["cell"]["chips"], t0, mode=mode,
         fault=fault)
 
@@ -316,10 +335,13 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t_start: float,
     devices = chips_for(jax, chips, require_accelerator)
     kind = devices[0].device_kind
     log(f"cell {workload}: {chips} x {kind} ({devices[0].platform}), "
-        f"seed {seed}, compile cache {cache}")
+        f"averager {r['train']['averager']}, seed {seed}, compile cache "
+        f"{cache}")
 
     from repro import compat
+    t_build = time.perf_counter()
     tr, data, t0 = build(jax, r, seed, devices)
+    t_built = time.perf_counter()
     n_params = sum(int(np.prod(l.shape[1:]))
                    for l in jax.tree.leaves(tr.params))
     print(f"{r['spec']['name']}: {n_params} parameters", flush=True)
@@ -327,7 +349,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t_start: float,
     with compat.set_mesh(tr.mesh):
         prog, read_s = program_readings(jax, r, tr, t0)
         log(f"checked steps: losses {prog['losses']!r}")
-        t = warm_rest(tr, t0)
+        t_checked = time.perf_counter()
+        t = warm_rest(tr, t0, r["train"]["tau"])
+        log(f"set-up: start to build {t_build - t_start!r} s, build "
+            f"{t_built - t_build!r} s, checked steps {t_checked - t_built!r}"
+            f" s (their reads {read_s!r} s), rest of the warm-up "
+            f"{time.perf_counter() - t_checked!r} s")
         peak_setup = peak_bytes(devices)
         c0 = stats.count()
         # set-up's objects out of the collector's reach, and no collection
@@ -356,7 +383,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t_start: float,
                     failed += 1
                 t += 1
                 if trace:
-                    if len(times) >= r["spec"]["train"]["tau"]:  # one period
+                    if len(times) >= r["train"]["tau"]:
                         break
                 elif e - w_start >= seconds:
                     break
@@ -384,7 +411,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, t_start: float,
         "setup_s": setup_s, "step_s": times, "window_s": window_s,
         "chips": chips, "tokens_per_step": generator.tokens_per_step(mix),
         "flops_per_step": yardstick.train_flops_per_step(r["spec"], mix),
-        "steps": len(times), "device_kind": kind,
+        "steps": len(times), "device_kind": kind, "spec": r["spec"],
+        "traffic": mix,
     }
     device = {"platform": devices[0].platform, "kind": kind, "count": chips,
               "memory_peak_bytes": peak}

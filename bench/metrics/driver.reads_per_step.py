@@ -7,7 +7,5 @@ from bench import scopes
 
 
 def read(ctx):
-    reads = scopes.readings(ctx)["host_reads"]
-    if len(reads) < 2:
-        return None
-    return (reads[-1] - reads[0]) / (len(reads) - 1)
+    return scopes.stat_rise_per_step(ctx, "trainer.read_metrics",
+                                     "host_reads")

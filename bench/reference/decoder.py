@@ -15,7 +15,19 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from bench import yardstick
 from bench.reference import common as C
+
+# the cut of a cell of this family to a size a CPU test run can hold:
+# the configuration's sizes, the program's fields and the traffic's
+TINY = {
+    "spec": dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+                 num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+                 vocab_size=500, embedding_rows=512),
+    "program": dict(d_model=64, d_ff=128, n_layers=2, n_heads=4,
+                    n_kv_heads=2, head_dim=16, vocab=500),
+    "traffic": dict(seq_len=32, batch_per_worker=2, distinct_batches=4),
+}
 
 
 def shapes(spec: dict, dtype=jnp.bfloat16):
@@ -84,3 +96,22 @@ def loss_and_grad(spec, mm, params, batch):
         body, (jnp.zeros((), jnp.float32), g0),
         (batch["tokens"], batch["labels"]))
     return tot / n_tok, jax.tree.map(lambda a: a / n_tok, g)
+
+
+def fwd_flops_per_token(c: dict, seq_len: int) -> float:
+    """One token of a causal sequence (``bench/yardstick.py``'s
+    conventions)."""
+    d, h, kh = c["hidden_size"], c["num_attention_heads"], \
+        c["num_key_value_heads"]
+    hd, ff, v = c["head_dim"], c["intermediate_size"], c["vocab_size"]
+    score = 2 * 2 * ((seq_len + 1) / 2) * h * hd
+    mlp = 2 * d * ff * (3 if c["hidden_act"] == "silu" else 2)
+    return c["num_hidden_layers"] * (
+        yardstick.attn_proj_flops(d, h, kh, hd) + score + mlp) + 2 * d * v
+
+
+def fwd_flops(spec: dict, traffic: dict) -> float:
+    """Model FLOPs of the forward over every row of a global step."""
+    rows = traffic["batch_per_worker"] * traffic["workers"]
+    return rows * traffic["seq_len"] * fwd_flops_per_token(
+        spec, traffic["seq_len"])

@@ -18,7 +18,20 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from bench import yardstick
 from bench.reference import common as C
+
+# the cut of a cell of this family to a size a CPU test run can hold:
+# the configuration's sizes, the program's fields and the traffic's
+TINY = {
+    "spec": dict(d_model=64, d_ff=128, num_heads=4, d_k=16, d_v=16,
+                 vocab_size=500, embedding_rows=512, num_encoder_layers=2,
+                 num_decoder_layers=2),
+    "program": dict(d_model=64, d_ff=128, n_layers=2, encoder_layers=2,
+                    n_heads=4, n_kv_heads=4, head_dim=16, vocab=500),
+    "traffic": dict(seq_len=16, source_len=16, batch_per_worker=4,
+                    distinct_batches=4),
+}
 
 
 def shapes(spec: dict, dtype=jnp.bfloat16):
@@ -101,3 +114,26 @@ def loss_and_grad(spec, mm, params, batch):
         return per_row.sum() / n_tok
 
     return jax.value_and_grad(total)(params)
+
+
+def pair_fwd_flops(c: dict, tgt_len: int, src_len: int) -> float:
+    """One (source, target) pair (``bench/yardstick.py``'s conventions)."""
+    d, h, hd, ff, v = (c["d_model"], c["num_heads"], c["d_k"], c["d_ff"],
+                       c["vocab_size"])
+    proj = yardstick.attn_proj_flops(d, h, h, hd)
+    mlp = 2 * 2 * d * ff
+    enc_tok = (proj + 2 * 2 * src_len * h * hd + mlp)
+    cross_kv_tok = 2 * 2 * d * h * hd           # per source token, per layer
+    dec_tok = (proj + 2 * 2 * ((tgt_len + 1) / 2) * h * hd
+               + 2 * 2 * d * h * hd + 2 * 2 * src_len * h * hd + mlp)
+    enc = src_len * (c["num_encoder_layers"] * enc_tok
+                     + c["num_decoder_layers"] * cross_kv_tok)
+    dec = tgt_len * (c["num_decoder_layers"] * dec_tok + 2 * d * v)
+    return enc + dec
+
+
+def fwd_flops(spec: dict, traffic: dict) -> float:
+    """Model FLOPs of the forward over every pair of a global step."""
+    rows = traffic["batch_per_worker"] * traffic["workers"]
+    return rows * pair_fwd_flops(spec, traffic["seq_len"],
+                                 traffic["source_len"])

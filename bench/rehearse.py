@@ -3,9 +3,10 @@
 
     JAX_PLATFORMS=cpu python3 bench/rehearse.py --workload <name>
 
-Builds the train step the cell's window runs (every butterfly phase and
-the tau-sync) at the configuration's real widths and the traffic's real
-shapes, for one described v5e chip or a described ``v5e:2x2``, and prints
+Builds the train step the cell's window runs (every phase of its averager
+and, under WAGMA, the tau-sync) at the configuration's real widths and the
+traffic's real shapes, for one described v5e chip or a described
+``v5e:2x2``, and prints
 per variant the compiler's memory analysis and the HLO's
 ``collective-permute``, ``all-reduce`` and ``tpu_custom_call`` counts.
 What the compiler refuses here costs no chip time; nothing here is a time.
@@ -42,7 +43,7 @@ def rehearse(workload: str) -> list:
     ops._default_interpret = lambda: False        # Mosaic, as on the chip
     r = harness.resolve(harness.load_manifest(), workload)
     chips, spec, mix = r["cell"]["chips"], r["spec"], r["traffic"]
-    train = spec["train"]
+    train = r["train"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     mesh = make_mesh((chips, 1), ("data", "model"),
@@ -50,8 +51,9 @@ def rehearse(workload: str) -> list:
     names, sizes = dp_axis_layout(mesh.axis_names, dict(mesh.shape),
                                   dp_axes_of(mesh))
     model = build_model(harness.program_config(spec))
-    av = make_averager("wagma", names, sizes, group_size=train["group_size"],
-                       tau=train["tau"])
+    kw = {"group_size": train["group_size"], "tau": train["tau"]} \
+        if train["averager"] == "wagma" else {}
+    av = make_averager(train["averager"], names, sizes, **kw)
     opt = sgd(train["learning_rate"], momentum=train["momentum"])
     key = jax.random.PRNGKey(0)
     rows = mix["batch_per_worker"] * chips
@@ -65,8 +67,8 @@ def rehearse(workload: str) -> list:
         sh = batch_shardings(mesh, sds)
         batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sh[k])
                  for k, v in sds.items()}
-        variants = [("sync", 0, True)] + [(f"group{p}", p, False)
-                                          for p in range(av.n_phases)]
+        variants = [("sync", 0, True)] * (train["averager"] == "wagma") \
+            + [(f"group{p}", p, False) for p in range(av.n_phases)]
         for tag, phase, sync in variants:
             t = time.perf_counter()
             step = build_train_step(model, opt, av, mesh, phase=phase,

@@ -17,30 +17,31 @@ as ``[name, start_ns, dur_ns, stats]``.  A TPU op's ``op_name`` is the
 ``ProfileData`` does not show: ``op_names`` reads it from the ``.xplane.pb``
 with a schema of the few XSpace fields it needs, and matches it to the
 events by their HLO text (an op of the same text in two programs takes
-one of their names).  ``reduce`` adds, per device and averaged over the
-devices, in seconds over the window (``trace.window``):
+one of their names).
 
-* ``scope_s``: the union of the intervals of the leaf ops (loop containers
-  left out) of each scope, clipped to the window.  Each op falls in one of
-  ``forward`` (a path segment ``forward``, under any transform but
-  ``transpose``), ``backward`` (``transpose(jvp(forward))``, the
-  recompute included), ``optimizer``, ``average`` (``average`` or
-  ``sync``) and ``unscoped``; besides, ``recompute``
-  (``rematted_computation``, a part of backward) and ``attention`` (an
-  ``attention`` segment, in forward, backward and recompute alike).  Where
-  an op's ``op_name`` joins several names with ``;`` (a fusion of ops of
-  several origins), the first name decides.  An op with no ``op_name`` (a
-  copy, slice or broadcast the compiler added, or the wait for one) takes
-  that of the leaf op before it on its device: it serves the computation
-  it runs amid;
-* ``leaf_s``: the union of every leaf op's interval, clipped to the window;
-* ``scope_ops``: how many ops of the window carry each scope;
+Each op falls in scopes by ``scopes_of``: one phase of ``forward`` (a path
+segment ``forward``, under any transform but ``transpose``), ``backward``
+(``transpose(jvp(forward))``, the recompute included), ``optimizer``,
+``average`` (``average`` or ``sync``) and ``unscoped``; besides,
+``recompute`` (``rematted_computation``, a part of backward) and
+``attention`` (an ``attention`` segment, in forward, backward and recompute
+alike); and every other named-scope segment of its ``op_name``
+(``jax.named_scope``'s names and the functions of nested jits, bare of
+JAX's transform wrappers).  Where an op's ``op_name`` joins several names
+with ``;`` (a fusion of ops of several origins), the first name decides.
+An op with no ``op_name`` (a copy, slice or broadcast the compiler added,
+or the wait for one) takes that of the leaf op before it on its device: it
+serves the computation it runs amid.
+
+``reduce`` gives, per device and averaged over the devices, in seconds
+over the window (``trace.window``):
+
+* ``leaf_s``: the union of every leaf op's interval (loop containers left
+  out), clipped to the window;
 * ``idle_s``: device idle time in the window (the complement of the union
   of every op's interval, as ``trace.reduce`` has it), and ``idle_in_s``:
   that idle time inside the union of the intervals of ``READ_SPANS``
   (``read``) and of ``LAUNCH_SPANS`` (``launch``);
-* ``host_reads``: the ``host_reads`` stat of each ``trainer.read_metrics``
-  span in the window, in order;
 * ``clock_shift_s``: how far each device's times were moved first.  The
   profiler puts the device planes on the host's clock only to within about
   a millisecond, and a step's device work then seems to start before the
@@ -51,6 +52,14 @@ devices, in seconds over the window (``trace.window``):
   their counts agree.  A device that already reads no earlier than its
   dispatch is left as it is: the shift corrects the sign of the skew, not
   its size.
+
+The metric readers' side reduces a scope on demand and keeps it in
+``ctx``: ``by_name`` and ``scope_ms`` (the union of the intervals of the
+leaf ops that carry any one name), ``calls_per_step`` (a kernel's calls by
+its op name's pattern) and ``roofline_share`` (a kernel's roofline time
+over its scope's device time).  A counter that the program stamps on a
+host span as a stat reads through ``stat_rise_per_step``.  A metric on a
+new scope, kernel or counter is then a metric file alone.
 """
 
 from __future__ import annotations
@@ -81,11 +90,15 @@ def _segment(*names):
 
 _FORWARD, _ATTENTION = _segment("forward"), _segment("attention")
 _OPTIMIZER, _AVERAGE = _segment("optimizer"), _segment("average", "sync")
+_BARE = re.compile(r"(?:\w+\()*(.*?)\)*")
 
 
 def scopes_of(op_name: str) -> tuple:
     """The scopes of a device op from its ``op_name``: its phase (one of
-    ``PHASES``), then ``recompute`` and ``attention`` where they apply."""
+    ``PHASES``), then ``recompute`` and ``attention`` where they apply,
+    then every other named-scope segment of its path (each segment but the
+    last, which names the op's primitive), bare of transform wrappers; a
+    segment named like one of ``SCOPES`` keeps that scope's meaning."""
     segs = op_name.split(";", 1)[0].split("/")
     has = lambda rx: any(rx.fullmatch(s) for s in segs)
     fwd = [s for s in segs if _FORWARD.fullmatch(s)]
@@ -103,7 +116,14 @@ def scopes_of(op_name: str) -> tuple:
         out += ("recompute",)
     if has(_ATTENTION):
         out += ("attention",)
+    for seg in segs[:-1]:
+        bare = _BARE.fullmatch(seg).group(1)
+        if bare and bare not in out and bare not in SCOPES:
+            out += (bare,)
     return out
+
+
+_scopes = functools.lru_cache(maxsize=None)(scopes_of)
 
 
 @functools.lru_cache(maxsize=1)
@@ -230,9 +250,30 @@ def intersect(a, b) -> list:
     return out
 
 
+def device_ops(tr: dict, did: str, shift_ns: int = 0):
+    """Device ``did``'s ops in time order, moved later by ``shift_ns``:
+    ``(name, [start, end], kind, op_name)``, where a leaf op with no
+    ``op_name`` takes that of the leaf op before it."""
+    last = ""
+    for name, start, dur, kind, op_name in sorted(
+            tr["devices"].get(did, []), key=lambda o: o[1]):
+        start += shift_ns
+        if kind != "container":
+            last = op_name or last
+        yield name, [start, start + dur], kind, last
+
+
+def stamps(host, lo: int, hi: int, span: str, stat: str) -> list:
+    """The ``stat`` of each ``span`` host span that starts in the window
+    and carries it, in time order."""
+    return [st[stat] for name, s, _, st in sorted(host, key=lambda h: h[1])
+            if name == span and lo <= s < hi and stat in st]
+
+
 def reduce(tr: dict, device_ids=None) -> dict:
-    """Per-scope device time, idle time inside the program's host spans and
-    the read counter's stamps, over the window."""
+    """Leaf and idle device time, and idle time inside the program's host
+    spans, over the window; with the window and the devices' clock shifts,
+    for the reductions made on demand."""
     host = tr["host"]
     lo, hi, _ = T.window([h[:3] for h in host])
     ids = [str(i) for i in device_ids] if device_ids is not None \
@@ -240,33 +281,17 @@ def reduce(tr: dict, device_ids=None) -> dict:
     spans = lambda names: T.clip(T.union(
         [s, s + d] for n, s, d, _ in host if n in names), lo, hi)
     program = {"read": spans(READ_SPANS), "launch": spans(LAUNCH_SPANS)}
-    sums = {k: 0 for k in SCOPES + ("leaf",)}
     idle_in = {k: 0 for k in program}
-    idle_total = 0
-    counts = {k: 0 for k in SCOPES}
-    of = functools.lru_cache(maxsize=None)(scopes_of)
-    shift = 0
+    leaf_total = idle_total = 0
+    shifts = {}
     for did in ids:
-        dt = clock_shift(host, tr.get("modules", {}).get(did, []))
-        shift += dt
-        by_scope = {k: [] for k in SCOPES}
-        leaf, busy, last = [], [], ""
-        for _, start, dur, kind, op_name in sorted(
-                tr["devices"].get(did, []), key=lambda o: o[1]):
-            start += dt
-            iv = [start, start + dur]
+        dt = shifts[did] = clock_shift(host, tr.get("modules", {}).get(did, []))
+        leaf, busy = [], []
+        for _, iv, kind, _ in device_ops(tr, did, dt):
             busy.append(iv)
-            if kind == "container":
-                continue
-            leaf.append(iv)
-            last = op_name or last
-            in_window = start < hi and start + dur > lo
-            for k in of(last):
-                by_scope[k].append(iv)
-                counts[k] += in_window
-        for k, ivs in by_scope.items():
-            sums[k] += T.total(T.clip(T.union(ivs), lo, hi))
-        sums["leaf"] += T.total(T.clip(T.union(leaf), lo, hi))
+            if kind != "container":
+                leaf.append(iv)
+        leaf_total += T.total(T.clip(T.union(leaf), lo, hi))
         busy = T.clip(T.union(busy), lo, hi)
         edges = [lo] + [x for iv in busy for x in iv] + [hi]
         idle = [[s, e] for s, e in zip(edges[::2], edges[1::2]) if e > s]
@@ -274,39 +299,106 @@ def reduce(tr: dict, device_ids=None) -> dict:
         for k, ivs in program.items():
             idle_in[k] += T.total(intersect(idle, ivs))
     n = max(len(ids), 1)
-    reads = [st["host_reads"] for name, s, _, st in sorted(
-        host, key=lambda h: h[1])
-        if name in READ_SPANS and lo <= s < hi and "host_reads" in st]
     return {
-        "scope_s": {k: sums[k] / n / 1e9 for k in SCOPES},
-        "leaf_s": sums["leaf"] / n / 1e9,
-        "scope_ops": counts,
+        "leaf_s": leaf_total / n / 1e9,
         "idle_s": idle_total / n / 1e9,
         "idle_in_s": {k: v / n / 1e9 for k, v in idle_in.items()},
         "span_s": {k: T.total(v) / 1e9 for k, v in program.items()},
-        "host_reads": reads,
-        "clock_shift_s": shift / n / 1e9,
+        "clock_shift_s": sum(shifts.values()) / n / 1e9,
+        "window_ns": [lo, hi],
+        "shift_ns": shifts,
     }
+
+
+# -- the metric readers' side --------------------------------------------------
+
+def trace_of(ctx: dict) -> dict:
+    """The traced run's trace in this module's plain form, loaded once from
+    the harness's trace directory and kept in ``ctx``."""
+    if "scope_trace" not in ctx:
+        from bench.harness import TRACE_DIR
+        ctx["scope_trace"] = load(TRACE_DIR)
+    return ctx["scope_trace"]
 
 
 def readings(ctx: dict) -> dict:
     """``reduce`` of the traced run's trace, for the metric readers: made
-    once from the harness's trace directory and kept in ``ctx``, whose
-    reduced trace (``ctx["trace"]``) names the devices."""
+    once and kept in ``ctx``, whose reduced trace (``ctx["trace"]``) names
+    the devices."""
     if "scopes" not in ctx:
-        from bench.harness import TRACE_DIR
-        ctx["scopes"] = reduce(load(TRACE_DIR),
+        ctx["scopes"] = reduce(trace_of(ctx),
                                list(ctx["trace"]["per_device"]))
     return ctx["scopes"]
+
+
+def by_name(ctx: dict, name: str) -> dict:
+    """Device seconds of the leaf ops that carry scope ``name``, any name
+    ``scopes_of`` reports (the union of their intervals, clipped to the
+    window, averaged over the devices), and how many ops of the window
+    carry it; reduced on the first call for ``name`` and kept in ``ctx``."""
+    red = readings(ctx)
+    cache = red.setdefault("by_name", {})
+    if name not in cache:
+        tr = trace_of(ctx)
+        lo, hi = red["window_ns"]
+        total = ops = 0
+        for did, dt in red["shift_ns"].items():
+            mine = []
+            for _, iv, kind, op_name in device_ops(tr, did, dt):
+                if kind != "container" and name in _scopes(op_name):
+                    mine.append(iv)
+                    ops += iv[0] < hi and iv[1] > lo
+            total += T.total(T.clip(T.union(mine), lo, hi))
+        n = max(len(red["shift_ns"]), 1)
+        cache[name] = {"s": total / n / 1e9, "ops": ops}
+    return cache[name]
 
 
 def scope_ms(ctx: dict, scope: str):
     """Device milliseconds per step in ``scope``; None where no op of the
     window carries it (a program without the scope)."""
-    r = readings(ctx)
-    if not r["scope_ops"][scope]:
+    got = by_name(ctx, scope)
+    if not got["ops"]:
         return None
-    return 1e3 * r["scope_s"][scope] / ctx["trace"]["steps"]
+    return 1e3 * got["s"] / ctx["trace"]["steps"]
+
+
+def calls_per_step(ctx: dict, pattern: str,
+                   scope: str | None = None) -> float:
+    """Leaf ops per step, averaged over the devices, whose name (the HLO
+    instruction's, such as a kernel's ``splash_mqa_fwd_residuals.19``)
+    matches the regular expression ``pattern`` from its start, which start
+    in the window and, where ``scope`` is given, carry that scope."""
+    rx = re.compile(pattern)
+    red, tr = readings(ctx), trace_of(ctx)
+    lo, hi = red["window_ns"]
+    n = sum(kind != "container" and lo <= iv[0] < hi and bool(rx.match(name))
+            and (scope is None or scope in _scopes(op_name))
+            for did, dt in red["shift_ns"].items()
+            for name, iv, kind, op_name in device_ops(tr, did, dt))
+    return n / max(len(red["shift_ns"]), 1) / ctx["trace"]["steps"]
+
+
+def stat_rise_per_step(ctx: dict, span: str, stat: str):
+    """A counter the program stamps as the stat ``stat`` of each ``span``
+    host span: its rise from the window's first such span to its last,
+    over the steps between them; None with fewer than two stamps."""
+    lo, hi = readings(ctx)["window_ns"]
+    got = stamps(trace_of(ctx)["host"], lo, hi, span, stat)
+    if len(got) < 2:
+        return None
+    return (got[-1] - got[0]) / (len(got) - 1)
+
+
+def roofline_share(ctx: dict, scope: str, roofline_s_per_step: float):
+    """Percent of the device time per step of the ops under ``scope`` that
+    the roofline time ``roofline_s_per_step`` (``yardstick.roofline_s`` of
+    the kernels' work) takes; None where no op of the window carries the
+    scope or the kernels read no work."""
+    ms = scope_ms(ctx, scope)
+    if not ms or not roofline_s_per_step:
+        return None
+    return 100.0 * 1e3 * roofline_s_per_step / ms
 
 
 def idle_ms(ctx: dict, spans: str):

@@ -1,9 +1,11 @@
-"""The yardstick: chip peaks and model FLOPs, from shapes.
+"""The yardstick: chip peaks, the FLOP conventions and kernel work, from
+shapes.
 
-Everything a per-layer metric divides by lives here, with its source, so
-that no change to the program can move it.
+Everything a per-layer metric divides by lives here or in the family's
+reference module, with its source, so that no change to the program can
+move it.
 
-Conventions:
+Conventions (each family module's ``fwd_flops`` keeps them):
 
 * Model FLOPs count one multiply-add as 2 FLOPs and only the matrix
   products the architecture requires (projections, attention scores and
@@ -15,10 +17,17 @@ Conventions:
   key.  This is the work the model needs, not what the blocked attention
   path happens to visit.
 * The unembedding counts the published vocabulary, not the padded table.
+
+A kernel's roofline time is the larger of its FLOPs over the chip's peak
+and its bytes over the HBM bandwidth (``roofline_s``), of the least work
+its layer needs (``attention_pass``), however an implementation splits it
+into calls; ``scopes.roofline_share`` divides it by the device time of the
+ops under the kernel's scope.
 """
 
 from __future__ import annotations
 
+import importlib
 from typing import NamedTuple
 
 
@@ -47,47 +56,51 @@ def peaks(device_kind: str) -> Peaks:
                        f" known: {sorted(PEAKS)}") from None
 
 
-def _attn_proj(d, h, kh, hd):
+def attn_proj_flops(d, h, kh, hd):
     """FLOPs per token of the q, k, v and o projections."""
     return 2 * (d * h * hd + 2 * d * kh * hd + h * hd * d)
 
 
-def decoder_fwd_flops_per_token(c: dict, seq_len: int) -> float:
-    """Dense decoder (qwen3 family), one token of a causal sequence."""
-    d, h, kh = c["hidden_size"], c["num_attention_heads"], \
-        c["num_key_value_heads"]
-    hd, ff, v = c["head_dim"], c["intermediate_size"], c["vocab_size"]
-    score = 2 * 2 * ((seq_len + 1) / 2) * h * hd
-    mlp = 2 * d * ff * (3 if c["hidden_act"] == "silu" else 2)
-    return c["num_hidden_layers"] * (_attn_proj(d, h, kh, hd) + score + mlp) \
-        + 2 * d * v
-
-
-def encdec_fwd_flops(c: dict, tgt_len: int, src_len: int) -> float:
-    """Encoder-decoder, one (source, target) pair."""
-    d, h, hd, ff, v = (c["d_model"], c["num_heads"], c["d_k"], c["d_ff"],
-                       c["vocab_size"])
-    mlp = 2 * 2 * d * ff
-    enc_tok = (_attn_proj(d, h, h, hd) + 2 * 2 * src_len * h * hd + mlp)
-    cross_kv_tok = 2 * 2 * d * h * hd           # per source token, per layer
-    dec_tok = (_attn_proj(d, h, h, hd) + 2 * 2 * ((tgt_len + 1) / 2) * h * hd
-               + 2 * 2 * d * h * hd + 2 * 2 * src_len * h * hd + mlp)
-    enc = src_len * (c["num_encoder_layers"] * enc_tok
-                     + c["num_decoder_layers"] * cross_kv_tok)
-    dec = tgt_len * (c["num_decoder_layers"] * dec_tok + 2 * d * v)
-    return enc + dec
-
-
 def train_flops_per_step(c: dict, traffic: dict) -> float:
-    """Model FLOPs of one training step over all workers (3x the forward)."""
-    rows = traffic["batch_per_worker"] * traffic["workers"]
-    if c["family"] == "decoder":
-        fwd = rows * traffic["seq_len"] * decoder_fwd_flops_per_token(
-            c, traffic["seq_len"])
-    elif c["family"] == "encdec":
-        fwd = rows * encdec_fwd_flops(c, traffic["seq_len"],
-                                      traffic["source_len"])
-    else:
-        raise ValueError(f"no FLOP count for family {c['family']!r}")
-    return 3.0 * fwd
+    """Model FLOPs of one training step over all workers (3x the forward).
 
+    The forward's count is the family's: ``fwd_flops(spec, traffic)`` of
+    ``bench/reference/<spec["reference"]>.py``, over every row of a global
+    step."""
+    name = f"bench.reference.{c['reference']}"
+    fwd_flops = getattr(importlib.import_module(name), "fwd_flops", None)
+    if fwd_flops is None:
+        raise ValueError(f"{name} has no fwd_flops(spec, traffic): no FLOP "
+                         f"count for family {c['family']!r}")
+    return 3.0 * fwd_flops(c, traffic)
+
+
+# -- kernels -------------------------------------------------------------------
+
+def roofline_s(flops: float, nbytes: float, p: Peaks) -> float:
+    """The least time the chip could take for ``flops`` and ``nbytes``."""
+    return max(flops / p.flops, nbytes / p.hbm_bw)
+
+
+# the least matrix products over the attended pairs of one attention pass:
+# the forward (or its recompute) QK^T and PV; the backward QK^T (for P),
+# dO V^T, P^T dO (dV), dS K (dQ) and dS^T Q (dK)
+ATTENTION_MATMULS = {"fwd": 2, "bwd": 5}
+ATTENTION_ITEMSIZE = 2     # q, k, v, o and their gradients in bfloat16
+
+
+def attention_pass(kind: str, batch: int, seq: int, heads: int,
+                   kv_heads: int, head_dim: int) -> tuple:
+    """(FLOPs, bytes) of one causal self-attention pass (``fwd`` or
+    ``bwd``) over ``batch`` rows: its matrix products over the S (S + 1) / 2
+    attended pairs per head alone, and each tensor it must read or write
+    once: the forward q, k, v -> o and the float32 logsumexp; the backward
+    q, k, v, o, dO and the logsumexp -> dq, dk, dv."""
+    pairs = seq * (seq + 1) // 2
+    flops = 2 * ATTENTION_MATMULS[kind] * pairs * head_dim * heads * batch
+    q = batch * seq * heads * head_dim * ATTENTION_ITEMSIZE   # also o, dO, dq
+    kv = batch * seq * kv_heads * head_dim * ATTENTION_ITEMSIZE  # k, v, dk, dv
+    lse = batch * heads * seq * 4
+    nbytes = {"fwd": 2 * q + 2 * kv + lse,
+              "bwd": 4 * q + 4 * kv + lse}[kind]
+    return flops, nbytes

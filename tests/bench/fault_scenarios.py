@@ -4,11 +4,14 @@
 
 Faults: ``sound`` (nothing broken), ``unchanged`` (the step returns its
 state unchanged), ``half_batch`` (each worker's step sees the first half
-of its rows, the mean taken over them).  Everything but the look for a chip
-runs as in ``bench/run.py``, with the cell's own limits.  Prints one JSON
-line per fault: its ``correct`` and its compared numbers.
+of its rows, the mean taken over them), ``no_exchange`` (the averager's
+exchange between chips left out: each replica keeps what it made).
+Everything but the look for a chip runs as in ``bench/run.py``, with the
+cell's own limits.  Prints one JSON line per fault: its ``correct`` and
+its compared numbers.
 """
 
+import copy
 import json
 import sys
 import time
@@ -26,6 +29,11 @@ def plant(fault: str, originals: dict):
     train_mod.build_train_step = real = originals["build_train_step"]
 
     def factory(model, opt, averager, mesh, **kw):
+        if fault == "no_exchange":
+            lone = copy.copy(averager)
+            lone.comm = lambda tree, phase: tree
+            lone.sync = lambda tree: tree
+            return real(model, opt, lone, mesh, **kw)
         step = real(model, opt, averager, mesh, **kw)
         workers = mesh.shape["data"]
 

@@ -4,9 +4,9 @@ can have, and true for the sound program, at a size a test run can hold.
 The control is the reference put in the program's place, computed in
 int8 (the precision below the configurations' bfloat16).  The faults are
 planted in the program under whole runs of the harness
-(``fault_scenarios.py``, one process per cell, all cells at once).  Every
-cell runs on one chip, so the exchange between chips is no fault a cell
-can have.
+(``fault_scenarios.py``, one process per cell, all cells at once, on as
+many CPU devices as the cell has chips).  Only a cell of several chips
+can leave out the exchange between chips.
 """
 
 import json
@@ -24,6 +24,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 FAULTS = {
     "qwen3-0.6b.train-2k.1chip": ("unchanged", "half_batch"),
     "transformer-wmt.train.1chip": ("unchanged", "half_batch"),
+    "transformer-wmt.allreduce.4chip": ("unchanged", "half_batch",
+                                        "no_exchange"),
 }
 CASES = [(w, f) for w, fs in FAULTS.items() for f in ("sound",) + fs]
 
@@ -65,7 +67,7 @@ def _readings(workload, mode):
     from bench.traffic import generator
     r = tiny_cell(workload)
     data = generator.batches(r["traffic"], r["spec"]["vocab_size"], 7)
-    t0 = harness.first_checked_step(r["spec"]["train"])
+    t0 = harness.first_checked_step(r["train"])
     return harness.reference_for(jax, r, data, t0, 7, mode=mode)
 
 
@@ -120,3 +122,62 @@ def test_gaps_take_the_worst_leaf_against_the_median():
     # against the median leaf's norm, and its change is not compared
     assert g["grad_gap"] == pytest.approx(0.5)
     assert g["change_gap"] == pytest.approx(0.25)
+
+
+def _linear_family():
+    """A one-leaf family: loss = mean(tokens * w), so each replica's
+    gradient is the mean of its rows."""
+    import types
+    import jax
+    import jax.numpy as jnp
+
+    def loss_and_grad(spec, mm, params, batch):
+        x = batch["tokens"].astype(jnp.float32)
+        return jax.value_and_grad(
+            lambda p: jnp.mean(x * p["w"][None]))(params)
+
+    return types.SimpleNamespace(loss_and_grad=loss_and_grad)
+
+
+@pytest.mark.parametrize("fault", [None, "no_exchange"])
+def test_allreduce_applies_the_mean_gradient_on_every_replica(fault):
+    import jax.numpy as jnp
+    import numpy as np
+    train = {"averager": "allreduce", "learning_rate": 0.5, "momentum": 0.9,
+             "tau": 10}
+    # two replicas of two rows each: replica 0's rows read 1, replica 1's 3
+    batch = {"tokens": np.array([[1, 1], [1, 1], [3, 3], [3, 3]], np.int32)}
+    out = correct.reference_readings(
+        _linear_family(), {}, train, {"w": jnp.zeros((2,), jnp.float32)},
+        [batch] * 2, 2, 0, fault=fault)
+    g = np.array([1.0, 3.0]) / 2 * np.sqrt(2)      # each replica's norm
+    if fault is None:
+        # the mean of the two, the same on both; two momentum steps
+        assert out["grad_norms"]["w"] == pytest.approx([2 / 2 * np.sqrt(2)] * 2)
+        assert out["change_norms"]["w"] == pytest.approx(
+            [0.5 * (1 + 1.9) * np.sqrt(2)] * 2)
+    else:
+        assert out["grad_norms"]["w"] == pytest.approx(g)
+        assert out["change_norms"]["w"] == pytest.approx(0.5 * 2.9 * g)
+
+
+def test_the_checked_steps_and_warm_up_follow_the_averager():
+    import types
+    assert harness.first_checked_step({"averager": "wagma", "tau": 10}) == 8
+    assert harness.first_checked_step({"averager": "allreduce",
+                                       "tau": 10}) == 0
+
+    def trainer(n_phases, sync_every):
+        ran = []
+        av = types.SimpleNamespace(
+            n_phases=n_phases, phase_for_step=lambda t: t % n_phases,
+            sync_due=lambda t: bool(sync_every) and (t + 1) % sync_every == 0)
+        return types.SimpleNamespace(averager=av, step_once=ran.append), ran
+
+    # allreduce: one variant, run on a step-made state by the checked steps
+    tr, ran = trainer(1, 0)
+    assert harness.warm_rest(tr, 0, 10) == 3 and ran == []
+    # WAGMA over two phases from tau - 2: group 0, sync, group 0 checked;
+    # group 1 comes next
+    tr, ran = trainer(2, 10)
+    assert harness.warm_rest(tr, 8, 10) == 12 and ran == [11]

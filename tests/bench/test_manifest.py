@@ -116,3 +116,20 @@ def test_limits_files_hold_every_number():
         with open(os.path.join(ROOT, "bench", "limits", f"{cell}.json")) as f:
             lim = json.load(f)["limits"]
         assert set(lim) == set(correct.NUMBERS)
+
+
+def test_cell_files_name_cells_and_averagers_the_reference_follows():
+    from bench import correct
+    folder = os.path.join(ROOT, "bench", "cells")
+    for name in sorted(os.listdir(folder)):
+        cell = name[:-len(".json")]
+        assert name.endswith(".json") and cell in CELLS
+        over = harness.cell_overrides(cell)
+        assert set(over) <= {"train", "why"}
+        assert set(over["train"]) <= {"averager", "group_size", "tau"}
+        train = harness.resolve(MANIFEST, cell)["train"]
+        assert train["averager"] in correct.AVERAGERS
+    assert harness.cell_overrides("qwen3-0.6b.train-2k.1chip") == \
+        {"train": {}}
+    assert harness.resolve(MANIFEST, "transformer-wmt.allreduce.4chip")[
+        "train"]["averager"] == "allreduce"

@@ -22,29 +22,45 @@ def span(name, start_ms, dur_ms, **stats):
     return [name, int(start_ms * MS), int(dur_ms * MS), stats]
 
 
+# the open segments of the paths above, after the phase and its parts
+LOOP = ("train_step", "while", "body", "closed_call")
+
+
 @pytest.mark.parametrize("op_name,want", [
-    (f"{FWD}/dot_general:", ("forward",)),
-    (f"{FWD}/attention/exp", ("forward", "attention")),
-    (f"{STEP}/forward/attention/exp", ("forward", "attention")),
-    (f"{BWD}/dot_general", ("backward",)),
-    (f"{BWD}/attention/mul", ("backward", "attention")),
-    (f"{RECOMPUTE}/add", ("backward", "recompute")),
+    (f"{FWD}/dot_general:", ("forward",) + LOOP),
+    (f"{FWD}/attention/exp", ("forward", "attention") + LOOP),
+    (f"{STEP}/forward/attention/exp", ("forward", "attention", "train_step")),
+    (f"{BWD}/dot_general", ("backward",) + LOOP + ("checkpoint",)),
+    (f"{BWD}/attention/mul",
+     ("backward", "attention") + LOOP + ("checkpoint",)),
+    (f"{RECOMPUTE}/add", ("backward", "recompute") + LOOP
+     + ("checkpoint", "rematted_computation")),
     (f"{RECOMPUTE}/attention/while/body/exp",
-     ("backward", "recompute", "attention")),
-    (f"{STEP}/optimizer/add", ("optimizer",)),
-    (f"{STEP}/average/convert_element_type", ("average",)),
-    (f"{STEP}/sync/psum", ("average",)),
-    (f"{STEP}/squeeze", ("unscoped",)),
-    (f"{STEP}/attention/broadcast_in_dim", ("unscoped", "attention")),
+     ("backward", "recompute", "attention") + LOOP
+     + ("checkpoint", "rematted_computation")),
+    (f"{STEP}/optimizer/add", ("optimizer", "train_step")),
+    (f"{STEP}/average/convert_element_type", ("average", "train_step")),
+    (f"{STEP}/sync/psum", ("average", "train_step", "sync")),
+    (f"{STEP}/squeeze", ("unscoped", "train_step")),
+    (f"{STEP}/attention/broadcast_in_dim",
+     ("unscoped", "attention", "train_step")),
     ("", ("unscoped",)),
     # a fusion of ops of several origins: the first name decides
-    (f"{STEP}/optimizer/add;{BWD}/mul", ("optimizer",)),
-    (f"{BWD}/mul;{STEP}/optimizer/add", ("backward",)),
+    (f"{STEP}/optimizer/add;{BWD}/mul", ("optimizer", "train_step")),
+    (f"{BWD}/mul;{STEP}/optimizer/add",
+     ("backward",) + LOOP + ("checkpoint",)),
     # names that only contain a scope's letters
-    (f"{STEP}/jvp()/while/body/dot_general", ("unscoped",)),
+    (f"{STEP}/jvp()/while/body/dot_general",
+     ("unscoped", "train_step", "while", "body")),
     (f"{STEP}/transpose(jvp())/checkpoint/rematted_computation/mul",
-     ("unscoped",)),
-    (f"{STEP}/forwarding/attention_mask/mul", ("unscoped",)),
+     ("unscoped", "train_step", "checkpoint", "rematted_computation")),
+    (f"{STEP}/forwarding/attention_mask/mul",
+     ("unscoped", "train_step", "forwarding", "attention_mask")),
+    # scopes a program adds, under transforms as the fixed ones are
+    (f"{STEP}/transpose(jvp(forward))/moe/experts/dot_general",
+     ("backward", "train_step", "moe", "experts")),
+    (f"{FWD}/attention/attention_kernel/shard_map/pallas_call",
+     ("forward", "attention") + LOOP + ("attention_kernel", "shard_map")),
 ])
 def test_scopes_of(op_name, want):
     assert S.scopes_of(op_name) == want
@@ -52,6 +68,18 @@ def test_scopes_of(op_name, want):
 
 def _steps(*spans):
     return [span("bench.step", s, d) for s, d in spans]
+
+
+def _ctx(tr, steps=2, ids=(0,)):
+    """A metric reader's context over the plain-form trace ``tr`` of the
+    devices ``ids``, ``steps`` steps long."""
+    return {"scope_trace": tr, "scopes": S.reduce(tr, list(ids)),
+            "trace": {"steps": steps}}
+
+
+def _fixed(ctx, key="s"):
+    """``by_name``'s ``key`` (seconds or ops) of each scope of ``SCOPES``."""
+    return {k: S.by_name(ctx, k)[key] for k in S.SCOPES}
 
 
 def test_scope_time_leaf_ops_only_clipped_to_the_window():
@@ -67,17 +95,16 @@ def test_scope_time_leaf_ops_only_clipped_to_the_window():
            op(17, 1, f"{STEP}/squeeze"),
            op(-2, 3, f"{FWD}/dot_general"),              # 1 ms in the window
            op(19, 4, f"{STEP}/optimizer/mul")]           # 1 ms in the window
-    red = S.reduce({"devices": {"0": dev}, "host": _steps((0, 10), (10, 10))},
-                   [0])
-    ms = {k: v * 1e3 for k, v in red["scope_s"].items()}
+    ctx = _ctx({"devices": {"0": dev}, "host": _steps((0, 10), (10, 10))})
+    red, got = ctx["scopes"], _fixed(ctx)
+    ms = {k: v * 1e3 for k, v in got.items()}
     assert ms == pytest.approx({"forward": 6, "backward": 9, "recompute": 4,
                                 "attention": 7, "optimizer": 2,
                                 "average": 1, "unscoped": 1})
     assert red["leaf_s"] * 1e3 == pytest.approx(19)
-    assert sum(red["scope_s"][k] for k in S.PHASES) == \
-        pytest.approx(red["leaf_s"])
-    assert red["scope_ops"]["forward"] == 3
-    assert red["scope_ops"]["optimizer"] == 2
+    assert sum(got[k] for k in S.PHASES) == pytest.approx(red["leaf_s"])
+    assert S.by_name(ctx, "forward")["ops"] == 3
+    assert S.by_name(ctx, "optimizer")["ops"] == 2
 
 
 def test_an_op_without_a_name_takes_the_scope_before_it():
@@ -86,21 +113,21 @@ def test_an_op_without_a_name_takes_the_scope_before_it():
     dev = [op(0, 1, name="copy-done.1"), op(1, 2, f"{BWD}/attention/mul"),
            op(3, 1, name="copy-done.2"), op(4, 2, f"{STEP}/optimizer/add"),
            op(6, 1, name="broadcast.3")]
-    red = S.reduce({"devices": {"0": dev}, "host": _steps((0, 10))}, [0])
-    ms = {k: v * 1e3 for k, v in red["scope_s"].items()}
+    ctx = _ctx({"devices": {"0": dev}, "host": _steps((0, 10))})
+    ms = {k: v * 1e3 for k, v in _fixed(ctx).items()}
     assert ms == pytest.approx({"forward": 0, "backward": 3, "recompute": 0,
                                 "attention": 3, "optimizer": 3,
                                 "average": 0, "unscoped": 1})
-    assert red["scope_ops"]["backward"] == 2
+    assert S.by_name(ctx, "backward")["ops"] == 2
 
 
 def test_scope_time_is_a_union_and_averaged_over_devices():
     # two overlapping backward ops on device 0 count 3 ms, not 4
     devs = {"0": [op(0, 2, f"{BWD}/a"), op(1, 2, f"{BWD}/b")],
             "1": [op(0, 1, f"{BWD}/a")]}
-    red = S.reduce({"devices": devs, "host": _steps((0, 10))}, [0, 1])
-    assert red["scope_s"]["backward"] * 1e3 == pytest.approx(2.0)
-    assert red["leaf_s"] * 1e3 == pytest.approx(2.0)
+    ctx = _ctx({"devices": devs, "host": _steps((0, 10))}, ids=(0, 1))
+    assert S.by_name(ctx, "backward")["s"] * 1e3 == pytest.approx(2.0)
+    assert ctx["scopes"]["leaf_s"] * 1e3 == pytest.approx(2.0)
 
 
 def test_idle_inside_the_program_spans():
@@ -123,17 +150,14 @@ def test_idle_inside_the_program_spans():
     assert red["idle_in_s"]["read"] * 1e3 == pytest.approx(3.0)
     assert red["span_s"]["launch"] * 1e3 == pytest.approx(4.0)
     assert red["span_s"]["read"] * 1e3 == pytest.approx(5.0)
-    assert red["host_reads"] == [3, 6]
+    assert S.stamps(host, *red["window_ns"], "trainer.read_metrics",
+                    "host_reads") == [3, 6]
 
 
 def test_intersect():
     assert S.intersect([[0, 2], [4, 8]], [[1, 5], [7, 9]]) == \
         [[1, 2], [4, 5], [7, 8]]
     assert S.intersect([[0, 2]], [[2, 3]]) == []
-
-
-def _ctx(red, steps=2):
-    return {"scopes": red, "trace": {"steps": steps}}
 
 
 def test_metric_readers_on_reduced_scopes():
@@ -143,7 +167,7 @@ def test_metric_readers_on_reduced_scopes():
         span("trainer.read_metrics", 14, 4, host_reads=3),
         span("trainer.dispatch", 18, 1, variant="sync", step=3),
         span("trainer.read_metrics", 19, 1, host_reads=6)]
-    ctx = _ctx(S.reduce({"devices": {"0": dev}, "host": host}, [0]))
+    ctx = _ctx({"devices": {"0": dev}, "host": host})
     read = lambda n: harness.metric_reader(n)(ctx)
     assert read("model.forward_ms") == pytest.approx(2.0)
     assert read("model.backward_ms") == pytest.approx(4.0)
@@ -159,8 +183,7 @@ def test_metric_readers_are_silent_without_the_program_names():
     """A program without the scopes, spans and counter reads nothing, and
     raises nothing."""
     dev = [op(0, 4, f"{STEP}/jvp()/dot_general"), op(4, 4, "")]
-    ctx = _ctx(S.reduce({"devices": {"0": dev}, "host": _steps((0, 10))},
-                        [0]), steps=1)
+    ctx = _ctx({"devices": {"0": dev}, "host": _steps((0, 10))}, steps=1)
     for name in ("model.forward_ms", "model.backward_ms",
                  "model.recompute_ms", "model.attention_ms",
                  "step.optimizer_ms", "driver.read_idle_ms",
@@ -214,13 +237,14 @@ def test_idle_is_attributed_after_the_clock_shift():
         span("trainer.read_metrics", 4, 6, host_reads=0)]
     tr = {"devices": {"0": dev}, "host": host,
           "modules": {"0": [[2 * MS, 5 * MS]]}}
-    red = S.reduce(tr, [0])
+    ctx = _ctx(tr)
+    red = ctx["scopes"]
     assert red["clock_shift_s"] * 1e3 == pytest.approx(1.0)
     # shifted to 3-8 ms: idle 0-3 (1-3 in put_batch) and 8-10 (in the read)
     assert red["idle_s"] * 1e3 == pytest.approx(5.0)
     assert red["idle_in_s"]["launch"] * 1e3 == pytest.approx(2.0)
     assert red["idle_in_s"]["read"] * 1e3 == pytest.approx(2.0)
-    assert red["scope_s"]["forward"] * 1e3 == pytest.approx(5.0)
+    assert S.by_name(ctx, "forward")["s"] * 1e3 == pytest.approx(5.0)
 
 
 def _recorded(name):
@@ -244,17 +268,19 @@ def test_recorded_scoped_trace():
     assert len(tr["devices"]["0"]) == 12084
     lo, hi, steps = T.window([h[:3] for h in tr["host"]])
     assert steps == 3
-    red = S.reduce(tr, [0])
+    ctx = _ctx(tr, steps)
+    red, got = ctx["scopes"], _fixed(ctx)
     # the scopes and the unscoped rest make up the leaf ops' time
-    assert sum(red["scope_s"][k] for k in S.PHASES) == \
+    assert sum(got[k] for k in S.PHASES) == \
         pytest.approx(red["leaf_s"], rel=1e-9)
-    assert red["scope_s"]["unscoped"] <= 0.05 * red["leaf_s"]
-    assert red["scope_s"] == pytest.approx({
+    assert got["unscoped"] <= 0.05 * red["leaf_s"]
+    assert got == pytest.approx({
         "forward": 0.015309015, "backward": 0.033238247,
         "recompute": 0.008467572, "attention": 0.026263578,
         "optimizer": 0.007077698, "average": 0.0, "unscoped": 0.000125825})
     # three blocking reads in each step
-    assert red["host_reads"] == [18, 21, 24]
+    assert S.stamps(tr["host"], *red["window_ns"], "trainer.read_metrics",
+                    "host_reads") == [18, 21, 24]
     # the device clock read 0.88 ms early: its first op of the window began
     # before the first dispatch did; shifted, every op of the window lies
     # between the first dispatch's start and the last read's end
@@ -271,3 +297,135 @@ def test_recorded_scoped_trace():
     assert sum(red["idle_in_s"].values()) >= 0.8 * red["idle_s"]
     assert red["idle_in_s"] == pytest.approx({"read": 0.007642648,
                                               "launch": 0.001367734})
+
+
+def test_recorded_scoped_trace_reads_as_pinned():
+    """The seven scopes and the read counter on the recorded trace, to the
+    last bit, as the reduction read them before scopes were opened: each
+    scope reduced by name on demand, as every metric reads it."""
+    from bench import trace as T
+    tr = _recorded("wmt-1chip-3steps-scoped.json.gz")
+    plain = {"devices": {k: [o[:4] for o in v]
+                         for k, v in tr["devices"].items()},
+             "host": [h[:3] for h in tr["host"]]}
+    ctx = {"scope_trace": tr, "trace": T.reduce(plain, [0])}
+    red = S.readings(ctx)
+    assert _fixed(ctx) == {
+        "forward": 0.015309015, "backward": 0.033238247,
+        "optimizer": 0.007077698, "average": 0.0, "unscoped": 0.000125825,
+        "recompute": 0.008467572, "attention": 0.026263578}
+    assert red["leaf_s"] == 0.055750785
+    assert _fixed(ctx, "ops") == {
+        "forward": 1713, "backward": 8358, "optimizer": 1836, "average": 0,
+        "unscoped": 87, "recompute": 1512, "attention": 3915}
+    assert red["idle_in_s"] == {"read": 0.007642648, "launch": 0.001367734}
+    assert red["idle_s"] == 0.009136412
+    assert red["clock_shift_s"] == 0.000882146
+    read = lambda n: harness.metric_reader(n)(ctx)
+    assert read("model.forward_ms") == 5.1030050000000005
+    assert read("model.backward_ms") == 11.079415666666668
+    assert read("model.recompute_ms") == 2.8225239999999996
+    assert read("model.attention_ms") == 8.754526
+    assert read("step.optimizer_ms") == 2.3592326666666668
+    assert read("driver.read_idle_ms") == 2.5475493333333334
+    assert read("driver.launch_idle_ms") == 0.45591133333333334
+    assert read("driver.reads_per_step") == 3.0
+    # open names of the same trace: the layer scans' checkpointed bodies,
+    # and the one-chip cell's absent exchange
+    assert 0 < S.scope_ms(ctx, "checkpoint") < read("model.backward_ms")
+    assert S.scope_ms(ctx, "sync") is None
+    assert read("averaging.exposed_ms") is None
+
+
+def _open_trace():
+    """Window 0..20 ms, two steps: a ``moe`` scope the fixed ones do not
+    know, and an exchange whose ops an optimiser op overlaps."""
+    moe = f"{STEP}/jvp(forward)/moe/experts"
+    dev = [op(0, 3, f"{moe}/dot_general", name="fusion.1"),
+           op(3, 1, name="copy.2"),                        # takes moe's name
+           op(4, 2, f"{BWD}/moe/dot_general", name="fusion.3"),
+           op(6, 4, f"{STEP}/average/psum", "collective", "all-reduce.4"),
+           op(8, 1, f"{STEP}/optimizer/add", name="fusion.5"),
+           op(12, 2, f"{STEP}/sync/psum", "collective", "all-reduce.6"),
+           op(15, 1, f"{moe}/custom", name="gmm_kernel.7"),
+           op(17, 1, f"{moe}/custom", name="gmm_kernel.8")]
+    host = _steps((0, 10), (10, 10)) + [
+        span("trainer.read_metrics", 9, 1, host_reads=3, moe_tokens=100),
+        span("trainer.read_metrics", 19, 1, host_reads=6, moe_tokens=400)]
+    return {"devices": {"0": dev}, "host": host}
+
+
+def test_an_open_scope_reduces_on_demand_and_is_kept():
+    ctx = _ctx(_open_trace())
+    # moe: 0-4 (the copy inherits), 4-6 in the backward, 15-16 and 17-18
+    assert S.scope_ms(ctx, "moe") == pytest.approx(8 / 2)
+    assert S.scope_ms(ctx, "experts") == pytest.approx(6 / 2)
+    assert S.by_name(ctx, "moe")["ops"] == 5
+    assert "moe" in ctx["scopes"]["by_name"]
+    assert S.scope_ms(ctx, "no_such_scope") is None
+
+
+def test_averaging_time_is_the_average_and_sync_scopes_device_time():
+    ctx = _ctx(_open_trace())
+    # average (average and sync): 6-10 and 12-14, the optimiser op at 8-9
+    # beside it included
+    assert S.scope_ms(ctx, "average") == pytest.approx(6 / 2)
+    assert harness.metric_reader("averaging.exposed_ms")(ctx) == \
+        pytest.approx(6 / 2)
+    assert S.scope_ms(ctx, "sync") == pytest.approx(2 / 2)
+
+
+def test_calls_per_step_count_a_kernels_ops_in_the_window():
+    ctx = _ctx(_open_trace())
+    assert S.calls_per_step(ctx, r"gmm_kernel") == pytest.approx(1.0)
+    assert S.calls_per_step(ctx, r"all-reduce") == pytest.approx(1.0)
+    assert S.calls_per_step(ctx, r"kernel") == 0
+    # only the calls under a scope, where one is named
+    assert S.calls_per_step(ctx, r"gmm_kernel", "moe") == pytest.approx(1.0)
+    assert S.calls_per_step(ctx, r"gmm_kernel", "average") == 0
+
+
+def test_a_counter_stamped_on_a_host_span_reads_its_rise_per_step():
+    ctx = _ctx(_open_trace())
+    assert S.stat_rise_per_step(ctx, "trainer.read_metrics",
+                                "moe_tokens") == pytest.approx(300)
+    assert S.stat_rise_per_step(ctx, "trainer.read_metrics",
+                                "host_reads") == pytest.approx(3)
+    assert S.stat_rise_per_step(ctx, "trainer.dispatch", "step") is None
+
+
+def test_attention_kernel_roofline_over_the_kernel_scope():
+    """Two steps, each with one forward, dq and dk/dv call of qwen3's
+    splash kernels at 4 x 2048 tokens, under ``attention_kernel`` with a
+    scaling op beside them: the share is their roofline time over the
+    scope's device time."""
+    from bench import yardstick as Y
+    r = harness.resolve(harness.load_manifest(), "qwen3-0.6b.train-2k.1chip")
+    sc = f"{FWD}/attention/attention_kernel"
+    dev = []
+    for t0 in (0, 10):
+        dev += [op(t0, 0.5, f"{sc}/mul", name="fusion.1"),
+                op(t0 + 1, 1, f"{sc}/pallas_call",
+                   name="splash_mqa_fwd_residuals.2"),
+                op(t0 + 2, 1, f"{sc}/pallas_call",
+                   name="splash_mqa_dq_no_residuals.3"),
+                op(t0 + 3, 2, f"{sc}/pallas_call",
+                   name="splash_mqa_dkv_no_residuals.4"),
+                # a call outside the scope is not in the share's time, and
+                # its work does not count either
+                op(t0 + 6, 1, f"{FWD}/pallas_call",
+                   name="splash_mqa_fwd_residuals.5")]
+    ctx = _ctx({"devices": {"0": dev}, "host": _steps((0, 10), (10, 10))})
+    ctx.update(spec=r["spec"], traffic=r["traffic"], device_kind="TPU v5 lite")
+    p = Y.peaks("TPU v5 lite")
+    # a forward pass (the fwd kernel) and a backward pass (dq and dk/dv)
+    want = sum(Y.roofline_s(*Y.attention_pass(k, 4, 2048, 16, 8, 128), p)
+               for k in ("fwd", "bwd"))
+    share = harness.metric_reader("attention.kernel_roofline")(ctx)
+    assert share == pytest.approx(100 * want / 4.5e-3)
+    # a program without the kernels reads nothing, not 0
+    quiet = _ctx({"devices": {"0": [op(0, 1, f"{FWD}/dot_general")]},
+                  "host": _steps((0, 10))}, steps=1)
+    quiet.update(spec=r["spec"], traffic=r["traffic"],
+                 device_kind="TPU v5 lite")
+    assert harness.metric_reader("attention.kernel_roofline")(quiet) is None

@@ -24,7 +24,8 @@ def test_qwen_flops_match_a_hand_count():
     # = 12582912; scores 4 * 1024.5 * 16 * 128 = 8392704; SwiGLU
     # 2 * 1024 * 3072 * 3 = 18874368; 28 layers; unembedding 2*1024*151936
     per_token = 28 * (12582912 + 8392704 + 18874368) + 311164928
-    assert yardstick.decoder_fwd_flops_per_token(r["spec"], 2048) == per_token
+    fam = harness.family_module(r["spec"])
+    assert fam.fwd_flops_per_token(r["spec"], 2048) == per_token
     assert per_token == 1426964480
     assert yardstick.train_flops_per_step(r["spec"], r["traffic"]) == \
         3 * 4 * 2048 * per_token
@@ -40,7 +41,8 @@ def test_wmt_flops_match_a_hand_count():
     # and the cross k and v projections of every decoder layer
     enc = 6 * (2097152 + 131072 + 4194304) + 6 * 1048576
     pair = 64 * dec + 64 * enc
-    assert yardstick.encdec_fwd_flops(r["spec"], 64, 64) == pair
+    fam = harness.family_module(r["spec"])
+    assert fam.pair_fwd_flops(r["spec"], 64, 64) == pair
     assert yardstick.train_flops_per_step(r["spec"], r["traffic"]) == \
         3 * 48 * pair
     four = dict(r["traffic"], workers=4)
@@ -104,3 +106,56 @@ def test_no_accelerator_exits_non_zero_with_no_result():
     assert p.returncode != 0
     assert "no accelerator" in p.stderr
     assert '"metrics"' not in p.stdout
+
+
+# flops_per_step of the committed cells, as the FLOP count read them before
+# it moved into the family modules
+PINNED_FLOPS = {
+    "qwen3-0.6b.train-2k.1chip": 35069079060480.0,
+    "transformer-wmt.train.1chip": 1179100643328.0,
+    "transformer-wmt.allreduce.4chip": 4716402573312.0,
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PINNED_FLOPS))
+def test_flops_per_step_is_pinned(cell):
+    r = _cell(cell)
+    assert yardstick.train_flops_per_step(r["spec"], r["traffic"]) == \
+        PINNED_FLOPS[cell]
+
+
+def test_a_family_without_a_flop_count_names_its_module():
+    spec = dict(_cell("qwen3-0.6b.train-2k.1chip")["spec"],
+                reference="common", family="nameless")
+    with pytest.raises(ValueError, match=r"bench\.reference\.common"):
+        yardstick.train_flops_per_step(
+            spec, _cell("qwen3-0.6b.train-2k.1chip")["traffic"])
+
+
+def test_roofline_s_takes_the_bound_that_binds():
+    p = yardstick.peaks("TPU v5 lite")
+    assert yardstick.roofline_s(197e12, 0, p) == pytest.approx(1.0)
+    assert yardstick.roofline_s(197e9, 819e9, p) == pytest.approx(1.0)
+    assert yardstick.roofline_s(0, 0, p) == 0
+
+
+def test_attention_pass_counts_causal_pairs_and_each_tensor_once():
+    # B 2, S 4, H 4, KH 2, hd 8: 10 causal pairs per head, 8 heads of rows
+    q, kv, lse = 2 * 4 * 4 * 8 * 2, 2 * 4 * 2 * 8 * 2, 2 * 4 * 4 * 4
+    # the forward: QK^T and PV; q k v -> o lse
+    assert yardstick.attention_pass("fwd", 2, 4, 4, 2, 8) == \
+        (2 * 2 * 10 * 8 * 4 * 2, 2 * q + 2 * kv + lse)
+    # the backward: five products; q k v o dO lse -> dq dk dv
+    assert yardstick.attention_pass("bwd", 2, 4, 4, 2, 8) == \
+        (2 * 5 * 10 * 8 * 4 * 2, 4 * q + 4 * kv + lse)
+
+
+def test_qwen_attention_kernels_are_bound_by_flops():
+    r = _cell("qwen3-0.6b.train-2k.1chip")
+    s, mix = r["spec"], r["traffic"]
+    p = yardstick.peaks("TPU v5 lite")
+    for kind in yardstick.ATTENTION_MATMULS:
+        flops, nbytes = yardstick.attention_pass(
+            kind, mix["batch_per_worker"], mix["seq_len"],
+            s["num_attention_heads"], s["num_key_value_heads"], s["head_dim"])
+        assert flops / p.flops > 2 * nbytes / p.hbm_bw
